@@ -239,8 +239,10 @@ def compute_benefits_batched(
     is computed ONCE at [N, P(, F)] and broadcast onto the Q axis; only the
     joint-probability update is per-query.  This is the jnp oracle the
     batched Pallas kernel (``repro.kernels.enrich_score``) is checked
-    against; the kernel additionally fuses the ``"best"``-mode argmax over F
-    so the [Q, N, P, F] intermediate below never reaches HBM.
+    against; the kernel additionally fuses each lane's ``"best"``-mode
+    argmax over F into the same pass.  Step 2's h is recomputed in f32 from
+    ``pred_prob`` (as in ``compute_benefits``); ``uncertainty``, which a
+    bf16 substrate stores rounded, only picks the table bin.
 
     Validity/candidate masking (pred_mask, §4.1 restriction) is the caller's
     job: returned benefits are unmasked except for exhausted triples.
@@ -248,32 +250,44 @@ def compute_benefits_batched(
     n, p = pred_prob.shape
     q = joint_prob.shape[0]
     pred_idx = jnp.broadcast_to(jnp.arange(p, dtype=jnp.int32)[None, :], (n, p))
+    # Under jit, costs closed over as constants would let XLA rewrite Eq. 11's
+    # division as a multiply by the reciprocal (1 ulp off on ~1/5 of lanes);
+    # the barrier keeps it a true division, whatever the caller passes.
+    costs = jax.lax.optimization_barrier(jnp.asarray(costs, jnp.float32))
 
     if function_selection == "best":
         assert table.delta_h_all is not None, "table learned without delta_h_all"
         dh_all = table.lookup_all(pred_idx, state_id, uncertainty)  # [N, P, F]
-        _, p_hat_all = estimate_pred_prob_after(
-            pred_prob[..., None], jnp.where(jnp.isfinite(dh_all), dh_all, 0.0)
-        )
-        cost = jnp.maximum(jnp.broadcast_to(costs[None], dh_all.shape), 1e-9)
-        est_all = jnp.clip(
-            conjunctive_joint_update(
-                joint_prob[:, :, None, None],
-                pred_prob[None, :, :, None],
-                p_hat_all[None],
-            ),
-            0.0,
-            1.0,
-        )  # [Q, N, P, F]
-        ben_all = joint_prob[:, :, None, None] * est_all / cost[None]
-        ben_all = jnp.where(jnp.isfinite(dh_all)[None], ben_all, NEG_INF)
-        nf = jnp.argmax(ben_all, axis=-1).astype(jnp.int32)  # [Q, N, P]
-        benefit = jnp.max(ben_all, axis=-1)
-        est_joint = jnp.take_along_axis(est_all, nf[..., None], axis=-1)[..., 0]
-        cost_q = jnp.take_along_axis(
-            jnp.broadcast_to(cost[None], est_all.shape), nf[..., None], axis=-1
-        )[..., 0]
-        nf = jnp.where(jnp.isfinite(benefit), nf, -1)
+        h = entropy_lib.binary_entropy(pred_prob)  # step 2's h, once for all F
+        # Eq. 11 argmax over F as a running max over a static F loop: nothing
+        # [Q, N, P, F]-shaped exists (on a TPU an F-minor f32 tensor is tiled
+        # 32x larger than its data).  Strict ">" keeps the FIRST maximum, so
+        # ties resolve exactly as ``argmax`` would.
+        benefit = jnp.full((q, n, p), NEG_INF, jnp.float32)
+        nf = jnp.full((q, n, p), -1, jnp.int32)
+        # lanes with no finite benefit keep est 0 and function 0's cost, as
+        # the fused kernel reports them
+        est_joint = jnp.zeros((q, n, p), jnp.float32)
+        cost_q = jnp.broadcast_to(jnp.maximum(costs[:, 0], 1e-9), (q, n, p))
+        for fi in range(dh_all.shape[-1]):
+            dh = dh_all[..., fi]
+            h_hat = jnp.clip(h + jnp.where(jnp.isfinite(dh), dh, 0.0), 0.0, 1.0)
+            p_hat = entropy_lib.inverse_entropy_upper(h_hat)
+            cost = jnp.maximum(costs[:, fi], 1e-9)[None, None, :]
+            est = jnp.clip(
+                conjunctive_joint_update(
+                    joint_prob[:, :, None], pred_prob[None], p_hat[None]
+                ),
+                0.0,
+                1.0,
+            )  # [Q, N, P]
+            ben = joint_prob[:, :, None] * est / cost
+            ben = jnp.where(jnp.isfinite(dh)[None], ben, NEG_INF)
+            better = ben > benefit
+            benefit = jnp.where(better, ben, benefit)
+            nf = jnp.where(better, fi, nf)
+            est_joint = jnp.where(better, est, est_joint)
+            cost_q = jnp.where(better, cost, cost_q)
         return TripleBenefits(
             benefit=benefit, next_fn=nf, est_joint=est_joint, cost=cost_q
         )

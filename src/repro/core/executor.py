@@ -73,7 +73,9 @@ class EngineConfig:
     function_selection: str = "table"  # "table" (paper) | "best" (beyond-paper)
     prior: float = 0.5
     backend: str = "jnp"  # "jnp" | "pallas" (fused batched scoring kernel)
-    pallas_interpret: Optional[bool] = None  # None: interpret iff CPU
+    # True runs the Pallas kernels in the interpreter (hosts without a TPU);
+    # the default compiles them for the device, never silently falling back
+    pallas_interpret: bool = False
     # >1: plan selection runs hierarchically over this many object shards
     # (per-shard top-k + exact cross-shard merge), byte-identical to the
     # unsharded path; the emulated-shard program is what each ("pod", "data")
@@ -294,10 +296,12 @@ class EpochProgram:
         self.combine_params = combine_params
         self.costs = jnp.asarray(costs, jnp.float32)
         self.config = config
-        # When a bank is attached, the superstep calls ``bank.execute(merged)``
-        # in-trace (its parameters and features become trace constants); when
-        # absent, outputs gather from the state-carried ``bank_outputs``
-        # buffer (banks publishing a precomputed ``.outputs`` tensor).
+        # When a bank is attached, the superstep calls ``bank.execute(merged,
+        # params)`` in-trace with the bank's ``params`` pytree (features,
+        # model weights) passed to the compiled scan as an ARGUMENT, never
+        # baked in as constants; when absent, outputs gather from the
+        # state-carried ``bank_outputs`` buffer (banks publishing a
+        # precomputed ``.outputs`` tensor).
         self.bank = bank
         if bank is not None and not scan_capable(bank):
             raise ValueError(
@@ -322,6 +326,11 @@ class EpochProgram:
     @property
     def num_functions(self) -> int:
         return self.costs.shape[1]
+
+    @property
+    def bank_params(self):
+        """The attached bank's arrays, a scan argument (None without a bank)."""
+        return None if self.bank is None else self.bank.params
 
     @property
     def superstep_traces(self) -> int:
@@ -489,7 +498,9 @@ class EpochProgram:
             merged = plan_lib.quarantine_filter(merged, state.quarantined)
         return plans, merged, want_bits
 
-    def _gather_outputs(self, state: SessionState, merged: plan_lib.Plan) -> jax.Array:
+    def _gather_outputs(
+        self, state: SessionState, merged: plan_lib.Plan, bank_params
+    ) -> jax.Array:
         """The bank boundary, fully inside the trace.
 
         With an attached bank, the merged plan runs through the bank's pure
@@ -503,7 +514,7 @@ class EpochProgram:
         are valid-masked.
         """
         if self.bank is not None:
-            probs = self.bank.execute(merged)
+            probs = self.bank.execute(merged, bank_params)
             return probs.astype(state.substrate.func_probs.dtype)
         obj = plan_lib.gather_object_idx(merged, state.capacity)
         return state.bank_outputs[obj, merged.pred_idx, jnp.maximum(merged.func_idx, 0)]
@@ -560,11 +571,11 @@ class EpochProgram:
             )(mask, self.truth_masks)
         return new_state, stats
 
-    def _superstep(self, state: SessionState, collect_masks: bool):
+    def _superstep(self, state: SessionState, bank_params, collect_masks: bool):
         """One plan -> execute -> apply -> attribute epoch as a pure scan body."""
         self._trace_count += 1  # Python side effect: fires per TRACE, not per step
         plans, merged, want_bits = self._plan_part(state)
-        outputs = self._gather_outputs(state, merged)
+        outputs = self._gather_outputs(state, merged, bank_params)
         new_state, stats = self._apply_part(state, plans, merged, want_bits, outputs)
         if not collect_masks:
             stats = {k: v for k, v in stats.items() if k != "answer_mask"}
@@ -581,9 +592,9 @@ class EpochProgram:
         key = (capacity, num_epochs, collect_masks, donate)
         if key not in self._scan_cache:
 
-            def run_fn(state):
+            def run_fn(state, bank_params):
                 return jax.lax.scan(
-                    lambda s, _: self._superstep(s, collect_masks),
+                    lambda s, _: self._superstep(s, bank_params, collect_masks),
                     state,
                     None,
                     length=num_epochs,
@@ -592,7 +603,7 @@ class EpochProgram:
             # donation lets XLA update the [C, P, F] state in place across
             # the dispatch instead of holding the pre-run copy alive.  The
             # session never donates (its state is a long-lived caller
-            # handle); the facades donate driver-created states off-CPU,
+            # handle); the facades donate states their own run created,
             # copying any leaves that alias engine-owned buffers first.
             argnums = (0,) if donate else ()
             self._scan_cache[key] = jax.jit(run_fn, donate_argnums=argnums)
@@ -622,7 +633,7 @@ class EpochProgram:
         """Dispatch ONE scan chunk without blocking; returns state + stats
         futures.  The building block of the async event pipeline."""
         fn = self._get_scan_fn(state.capacity, length, collect_masks, donate)
-        return fn(state)
+        return fn(state, self.bank_params)
 
     def run_scan(
         self,

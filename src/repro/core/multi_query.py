@@ -702,9 +702,9 @@ class MultiQueryEngine:
                 collect_masks=collect_masks,
             )
         session = self._session_for(num_objects)
-        # donate driver-created states off-CPU (the pre-facade policy):
-        # XLA updates the [N, P, F] tensors in place across the run
-        donate = created_here and jax.default_backend() != "cpu"
+        # donate states this run created (the pre-facade policy): XLA
+        # updates the [N, P, F] tensors in place across the run
+        donate = created_here
         sst, hist = session.program.run_scan(
             self._to_session_state(state, for_donation=donate),
             num_epochs, collect_masks=collect_masks,

@@ -324,8 +324,8 @@ class ProgressiveQueryOperator:
             # bank with no traceable execute — keep the per-epoch loop.
             return self._run_legacy_loop(state, num_epochs, stop_when_exhausted)
         session = self._session_for(num_objects)
-        # donate driver-created states off-CPU (the pre-facade policy)
-        donate = created_here and jax.default_backend() != "cpu"
+        # donate states this run created (the pre-facade policy)
+        donate = created_here
         sst, hist = session.program.run_scan(
             self._to_session_state(state, for_donation=donate),
             num_epochs,

@@ -6,12 +6,14 @@ section 3):
 
     level 0: linear probe                 (the pre-executed cheapest function)
     level 1: 2-layer MLP probe
-    level 2: assigned-arch-backbone head (reduced config on CPU; the full
-             config is what the dry-run serves on the production mesh)
+    level 2: assigned-arch-backbone head (the reduced smoke config on CPU;
+             the published widths on the chip, ``serve --backbone-size
+             published``)
 
-Costs are analytic FLOPs converted to seconds at the target chip's peak
-(197 TFLOPs bf16); qualities are measured AUC on a held-out validation
-split.
+Costs are analytic FLOPs divided by ``PEAK_FLOPS``: a fixed planner cost
+unit that ranks levels against each other, not a measured or predicted
+device time (it is never reported as one).  Qualities are measured AUC on
+a held-out validation split.
 
 ``ModelCascadeBank`` is a *traceable* bank (``supports_scan == True``): at
 construction the per-(predicate, level) parameters are stacked into
@@ -23,6 +25,10 @@ masked batched forward over the full lane vector (features gathered once,
 ``vmap`` over predicate heads), and probabilities scatter back through the
 inverse permutation.  That lets the whole plan -> execute -> apply epoch
 fuse into ``EpochProgram.run_scan`` with zero host round-trips per epoch.
+Every array ``execute`` reads (features, probe stacks, the backbone trunk
+and heads) is the ``params`` pytree, which the superstep takes as a jit
+ARGUMENT: a closed-over trunk would be baked into each executable as
+compile-time constants (gigabytes at published widths).
 ``execute_host`` keeps the legacy host-side numpy grouping (one jitted call
 per (pred, level)) as the parity reference and benchmark baseline.
 
@@ -36,6 +42,7 @@ via the quarantine channel.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Callable, Optional, Sequence
 
@@ -46,8 +53,10 @@ import numpy as np
 from repro.core.plan import Plan
 from repro.models import transformer as tf
 from repro.models.config import ModelConfig
-from repro.models.model import Model
 
+# The planner's cost unit: analytic FLOPs per object / PEAK_FLOPS.  A fixed
+# scale (the TPU v5e bf16 peak), so level costs stay comparable across
+# configs; it is NOT a device-time estimate and is never printed as one.
 PEAK_FLOPS = 197e12
 
 # Cost padding for (pred, level) slots a ragged cascade bank does not have.
@@ -117,6 +126,52 @@ def _backbone_apply(cfg: ModelConfig, trunk_params, head_params, feats):
     return jax.nn.sigmoid(pooled @ head_params["out"])[:, 0]
 
 
+def init_trunk(key, cfg: ModelConfig) -> dict:
+    """The backbone's transformer layers, weight matrices at the activation
+    dtype.
+
+    The tagging head feeds projected features straight into the layers, so
+    the token embedding is never built.  The layers draw the same random
+    stream as ``Model(cfg).init_params(key)["layers"]``.  Only the stacked
+    weight tensors (ndim >= 3) are cast, and every forward casts exactly
+    those to the activation dtype at use, so the cast halves the trunk's
+    bytes without changing what it computes; vectors (norms, SSM decay and
+    bias terms) stay f32.
+    """
+
+    @jax.jit
+    def init(k):
+        layers, _ = tf.stack_init(
+            jax.random.split(k, 8)[1], cfg, cfg.num_layers,
+            cross=cfg.encoder is not None,
+        )
+        return jax.tree.map(
+            lambda x: x.astype(cfg.activation_dtype) if x.ndim >= 3 else x,
+            layers,
+        )
+
+    return {"layers": init(key)}
+
+
+@functools.lru_cache(maxsize=None)
+def _backbone_fns(cfg: ModelConfig):
+    """(apply, head_grad) jitted once per config, trunk as an argument.
+
+    ``apply((trunk, head), feats) -> probs``; ``head_grad(trunk, head,
+    feats, labels)`` is the head's NLL gradient with the trunk frozen.
+    """
+
+    def apply(params, feats):
+        trunk, head = params
+        return _backbone_apply(cfg, trunk, head, feats)
+
+    def loss(trunk, head, feats, y):
+        pr = jnp.clip(apply((trunk, head), feats), 1e-6, 1 - 1e-6)
+        return -jnp.mean(y * jnp.log(pr) + (1 - y) * jnp.log(1 - pr))
+
+    return jax.jit(apply), jax.jit(jax.grad(loss, argnums=1))
+
+
 def _backbone_level(
     key,
     cfg: ModelConfig,
@@ -127,17 +182,13 @@ def _backbone_level(
     across predicates (per-predicate heads only) — the layout the fused bank
     requires; when omitted a private trunk is initialized."""
     if trunk_params is None:
-        model = Model(cfg)
-        trunk_params, _ = model.init_params(key)
+        trunk_params = init_trunk(key, cfg)
     k2 = jax.random.fold_in(key, 1)
     head = {
         "proj": jax.random.normal(k2, (feature_dim, cfg.d_model)) * 0.05,
         "out": jax.random.normal(jax.random.fold_in(k2, 1), (cfg.d_model, 1)) * 0.05,
     }
-
-    def apply_fn(p, feats):
-        model_params, head_params = p
-        return _backbone_apply(cfg, model_params, head_params, feats)
+    apply_fn, _ = _backbone_fns(cfg)
 
     # FLOP-honest cost: 2 * active params per token, N_BACKBONE_TOKENS tokens
     flops = 2.0 * cfg.param_counts()["active"] * N_BACKBONE_TOKENS
@@ -181,8 +232,7 @@ def build_cascade_suite(
     linear/MLP probes, one SHARED backbone trunk with per-predicate heads."""
     trunk = None
     if backbone_cfg is not None:
-        model = Model(backbone_cfg)
-        trunk, _ = model.init_params(jax.random.fold_in(key, 999))
+        trunk = init_trunk(jax.random.fold_in(key, 999), backbone_cfg)
     return [
         build_cascade(
             jax.random.fold_in(key, i), feature_dim,
@@ -197,20 +247,16 @@ def train_level(
     steps: int = 200, lr: float = 0.05,
 ) -> CascadeLevel:
     """Fit a level to planted labels with NLL descent.  Backbone levels
-    train only the (proj, out) head with the backbone frozen (full backbone
-    pretraining runs via launch/train.py)."""
+    train only the (proj, out) head with the backbone frozen; the trunk is
+    an argument of the jitted gradient, never a compile-time constant."""
     y = labels.astype(jnp.float32)
 
     if level.name.startswith("backbone"):
         backbone, head = level.params
-
-        def loss_h(h):
-            pr = jnp.clip(level.apply_fn((backbone, h), feats), 1e-6, 1 - 1e-6)
-            return -jnp.mean(y * jnp.log(pr) + (1 - y) * jnp.log(1 - pr))
-
-        g = jax.jit(jax.grad(loss_h))
+        _, head_grad = _backbone_fns(level.cfg)
         for _ in range(max(steps // 2, 50)):
-            head = jax.tree.map(lambda t, gg: t - lr * gg, head, g(head))
+            g = head_grad(backbone, head, feats, y)
+            head = jax.tree.map(lambda t, gg: t - lr * gg, head, g)
         return dataclasses.replace(level, params=(backbone, head))
 
     def loss(p):
@@ -235,7 +281,7 @@ class ModelCascadeBank:
 
     cascades: Sequence[Sequence[CascadeLevel]]  # [P][<=F]
     features: jax.Array  # [N, D]
-    costs: jax.Array = None  # [P, F] seconds (filled in __post_init__)
+    costs: jax.Array = None  # [P, F] planner cost units (filled in __post_init__)
     available: jax.Array = None  # [P, F] bool (filled in __post_init__)
 
     # the scan superstep may trace this bank's execute (see core.executor)
@@ -256,7 +302,8 @@ class ModelCascadeBank:
         self.available = jnp.asarray(avail)
         self.features = jnp.asarray(self.features)
         self._jitted = {}
-        self._stack = self._build_stack(p, f)
+        self._levels, level_params = self._build_stack(p, f)
+        self.params = {"features": self.features, "levels": level_params}
 
     @property
     def num_levels(self) -> int:
@@ -264,15 +311,16 @@ class ModelCascadeBank:
 
     # ---- stacked-parameter construction ------------------------------------
 
-    def _build_stack(self, p: int, f: int) -> list:
+    def _build_stack(self, p: int, f: int) -> tuple[list, list]:
         """Per level: one homogeneous [P]-leading parameter stack.
 
         Linear/MLP probes stack leaf-wise (predicates missing the level get
         zero-filled placeholders, masked out by ``available``).  Backbone
         levels must share ONE trunk across predicates; only the (proj, out)
-        heads stack.
+        heads stack.  -> (static per-level descriptions, per-level array
+        pytrees); the arrays go into ``params``.
         """
-        stack = []
+        levels, stack = [], []
         for j in range(f):
             present = {i: c[j] for i, c in enumerate(self.cascades) if len(c) > j}
             template = next(iter(present.values()))
@@ -289,9 +337,8 @@ class ModelCascadeBank:
                     present[i].params[1] if i in present else zero_head
                     for i in range(p)
                 ]
+                levels.append(dict(kind="backbone", cfg=template.cfg))
                 stack.append(dict(
-                    kind="backbone",
-                    cfg=template.cfg,
                     trunk=template.params[0],
                     heads=jax.tree.map(lambda *xs: jnp.stack(xs), *heads),
                 ))
@@ -307,12 +354,9 @@ class ModelCascadeBank:
                     present[i].params if i in present else zero
                     for i in range(p)
                 ]
-                stack.append(dict(
-                    kind="probe",
-                    apply=template.apply_fn,
-                    params=jax.tree.map(lambda *xs: jnp.stack(xs), *params),
-                ))
-        return stack
+                levels.append(dict(kind="probe", apply=template.apply_fn))
+                stack.append(jax.tree.map(lambda *xs: jnp.stack(xs), *params))
+        return levels, stack
 
     def _apply(self, pred: int, fn: int):
         key = (pred, fn)
@@ -332,7 +376,7 @@ class ModelCascadeBank:
 
     # ---- execution ----------------------------------------------------------
 
-    def execute(self, plan: Plan) -> jax.Array:
+    def execute(self, plan: Plan, params=None) -> jax.Array:
         """Fused traceable execute: every unique (object, pred, level) triple
         of the merged plan in one fixed-shape program.
 
@@ -348,12 +392,16 @@ class ModelCascadeBank:
 
         Works unchanged for single-query plans and for the multi-query
         engine's merged deduplicated plans, and — because every operand is a
-        fixed-shape jnp array — inside ``jit`` / ``lax.scan``.
+        fixed-shape jnp array — inside ``jit`` / ``lax.scan``.  ``params``
+        (default: this bank's own ``params``) supplies every array read, so
+        a traced caller passes them in as arguments.
         """
+        if params is None:
+            params = self.params
         p_num = len(self.cascades)
         f_num = self.num_levels
         m = plan.object_idx.shape[0]
-        n = self.features.shape[0]
+        n = params["features"].shape[0]
         valid = plan.valid
         obj = jnp.where(valid, jnp.clip(plan.object_idx, 0, n - 1), 0)
         prd = jnp.where(valid, jnp.clip(plan.pred_idx, 0, p_num - 1), 0)
@@ -365,17 +413,17 @@ class ModelCascadeBank:
         inv = jnp.argsort(order)
         s_obj, s_prd, s_fn = obj[order], prd[order], fns[order]
         s_valid = valid[order]
-        feats = self.features[s_obj].astype(jnp.float32)  # [M, D]
+        feats = params["features"][s_obj].astype(jnp.float32)  # [M, D]
         lane = jnp.arange(m)
 
         out = jnp.full((m,), 0.5, jnp.float32)
-        for j, entry in enumerate(self._stack):
+        for j, (level, arrays) in enumerate(zip(self._levels, params["levels"])):
             on = s_valid & (s_fn == j) & self.available[s_prd, j]
-            if entry["kind"] == "backbone":
-                cfg = entry["cfg"]
-                heads = entry["heads"]
+            if level["kind"] == "backbone":
+                cfg = level["cfg"]
+                heads = arrays["heads"]
 
-                def _backbone_probs(operands, cfg=cfg, heads=heads, entry=entry):
+                def _backbone_probs(operands, cfg=cfg, heads=heads, trunk=arrays["trunk"]):
                     feats, s_prd = operands
                     # per-predicate input/output heads via vmap-shaped
                     # einsums, one shared trunk pass over all M lanes
@@ -389,7 +437,7 @@ class ModelCascadeBank:
                         (m, N_BACKBONE_TOKENS),
                     )
                     h, _, _ = tf.stack_apply(
-                        entry["trunk"]["layers"], cfg, x, pos, cfg.num_layers,
+                        trunk["layers"], cfg, x, pos, cfg.num_layers,
                         causal=False,
                     )
                     pooled = jnp.mean(h.astype(jnp.float32), axis=1)
@@ -407,8 +455,8 @@ class ModelCascadeBank:
                     (feats, s_prd),
                 )
             else:
-                per_pred = jax.vmap(entry["apply"], in_axes=(0, None))(
-                    entry["params"], feats
+                per_pred = jax.vmap(level["apply"], in_axes=(0, None))(
+                    arrays, feats
                 )  # [P, M]
                 probs = per_pred[s_prd, lane]
             out = jnp.where(on, probs.astype(jnp.float32), out)

@@ -5,7 +5,7 @@ holds arriving micro-batches in a pre-allocated ``[K, B, P, F]`` device
 buffer at the session's substrate dtype:
 
 * ``push`` writes one micro-batch into the next free slot as a jitted
-  ``dynamic_update_slice`` with the ring buffer DONATED (off-CPU), so XLA
+  ``dynamic_update_slice`` with the ring buffer DONATED, so XLA
   updates it in place — no copy of K slots per arrival, no host sync (the
   slot index is a traced scalar; occupancy lives in host shadows).
 * ``drain_into`` replays every pending slot into an ``EngineSession`` as
@@ -55,16 +55,6 @@ def _write_slot_donated(buf, batch, slot):
     )
 
 
-@jax.jit
-def _write_slot(buf, batch, slot):
-    """CPU fallback: identical update without donation (jax warns on CPU
-    donation and falls back to a copy anyway — same convention as the
-    executor's facades)."""
-    return jax.lax.dynamic_update_slice(
-        buf, batch[None], (slot,) + (0,) * batch.ndim
-    )
-
-
 class PendingRing:
     """Bounded FIFO of pending ingest micro-batches on the device.
 
@@ -97,12 +87,6 @@ class PendingRing:
         p, f = session.num_predicates, session.num_functions
         self._buf = jnp.zeros(
             (self.num_slots, self.slot_rows, p, f), session.substrate_dtype
-        )
-        # donation only off-CPU (on CPU jax warns and copies anyway)
-        self._write = (
-            _write_slot_donated
-            if jax.devices()[0].platform != "cpu"
-            else _write_slot
         )
         # host shadows of occupancy: FIFO position + per-slot fill counts
         self._head = 0  # oldest pending slot
@@ -168,7 +152,7 @@ class PendingRing:
                 (self.slot_rows - m,) + batch.shape[1:], self._buf.dtype
             )
             batch = jnp.concatenate([batch, pad], axis=0)
-        self._buf = self._write(self._buf, batch, jnp.int32(slot))
+        self._buf = _write_slot_donated(self._buf, batch, jnp.int32(slot))
         self._fill[slot] = m
         self._count += 1
         self.counters["pushed_batches"] += 1

@@ -19,6 +19,10 @@ Two serving modes:
 CPU-scale usage (examples/serve_progressive.py drives this):
     python -m repro.launch.serve --objects 512 --epochs 40
     python -m repro.launch.serve --objects 256 --preds 3 --queries 8
+
+On a TPU, ``chip_smoke.py`` drives ``main`` at deployment scale, including a
+cascade session with ``--backbone-size published``.  Costs print in the
+planner's units (``enrich.cascade.PEAK_FLOPS``), never as device time.
 """
 
 from __future__ import annotations
@@ -27,7 +31,9 @@ import argparse
 import dataclasses
 import hashlib
 import json
+import os
 import time
+from pathlib import Path
 from typing import Optional
 
 import jax
@@ -72,12 +78,49 @@ class ServeReport:
     history: list
 
 
+def use_compile_cache(default_dir=None) -> Optional[str]:
+    """Turn on JAX's persistent compilation cache for this process.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is JAX's own setting and is left
+    alone, and so is a cache directory the calling program already chose.
+    Otherwise the cache goes to ``default_dir`` or, when this package runs
+    from a source checkout's ``src/``, to ``<checkout>/.jax_cache``: a fixed
+    path, since the path is part of the cache key.  Installed elsewhere with
+    no directory given, no persistent cache is turned on.  Call it from an
+    entry point before the first compile, never at import.  -> the cache
+    directory in use, or None.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    if jax.config.jax_compilation_cache_dir:
+        return jax.config.jax_compilation_cache_dir
+    if default_dir is None:
+        src = Path(__file__).resolve().parents[2]
+        if src.name != "src" or not (src.parent / "pyproject.toml").is_file():
+            return None
+        default_dir = src.parent / ".jax_cache"
+    jax.config.update("jax_compilation_cache_dir", str(default_dir))
+    return str(default_dir)
+
+
+def backbone_config(arch: Optional[str], size: str = "smoke"):
+    """``size="published"``: the architecture's published widths and depth;
+    ``"smoke"``: its reduced same-family config (CPU-scale)."""
+    if not arch:
+        return None
+    if size not in ("smoke", "published"):
+        raise ValueError(f"backbone size must be smoke|published, got {size!r}")
+    return get_config(arch, smoke=size == "smoke")
+
+
 def _offline_phase(
     num_objects: int,
     num_preds: int,
     backbone_arch: Optional[str],
     seed: int,
     train_size: int = 512,
+    backbone_size: str = "smoke",
 ):
     """Corpus + cascade training + combine/table learning over the GLOBAL
     predicate space (shared by single- and multi-tenant serving).
@@ -93,7 +136,7 @@ def _offline_phase(
     )
     train, evalc = split_corpus(corpus, train_size)
 
-    backbone_cfg = get_config(backbone_arch, smoke=True) if backbone_arch else None
+    backbone_cfg = backbone_config(backbone_arch, backbone_size)
     # one SHARED backbone trunk with per-predicate heads — the stacked
     # layout the fused traceable bank requires
     suite = build_cascade_suite(rng, num_preds, 64, backbone_cfg)
@@ -136,10 +179,12 @@ def build_server(
     num_preds: int = 1,
     backbone_arch: Optional[str] = "qwen3-1.7b",
     seed: int = 0,
+    backbone_size: str = "smoke",
 ):
     """-> (operator, corpus, truth).  Trains the cascade probes offline."""
     preds, evalc, bank, combine, table, qualities = _offline_phase(
-        num_objects, num_preds, backbone_arch, seed
+        num_objects, num_preds, backbone_arch, seed,
+        backbone_size=backbone_size,
     )
     query = conjunction(*preds)
     truth = truth_answer_mask(evalc, query)
@@ -159,6 +204,8 @@ def build_multi_server(
     preds_per_query: int = 2,
     plan_shards: int = 1,
     backend: str = "jnp",
+    backbone_size: str = "smoke",
+    pallas_interpret: bool = False,
 ):
     """Multi-tenant server: Q overlapping conjunctive queries, one substrate.
 
@@ -167,7 +214,8 @@ def build_multi_server(
     cross-query dedup pays.  -> (engine, corpus, truths, qualities, queries)
     """
     preds, evalc, bank, combine, table, qualities = _offline_phase(
-        num_objects, num_preds, backbone_arch, seed
+        num_objects, num_preds, backbone_arch, seed,
+        backbone_size=backbone_size,
     )
     rng = np.random.default_rng(seed + 1)
     queries = []
@@ -186,6 +234,7 @@ def build_multi_server(
     cfg = MultiQueryConfig(
         plan_size=64, function_selection="best",
         num_shards=plan_shards, backend=backend,
+        pallas_interpret=pallas_interpret,
     )
     engine = MultiQueryEngine(
         query_set, table, combine, bank.costs, bank, cfg, truth_masks=truths
@@ -330,6 +379,7 @@ def build_session_server(
     backend: str = "jnp",
     max_capacity: Optional[int] = None,
     substrate_dtype: str = "float32",
+    pallas_interpret: bool = False,
 ):
     """Long-lived serving session over a simulated (AUC-calibrated) corpus.
 
@@ -368,7 +418,7 @@ def build_session_server(
         config=MultiQueryConfig(
             plan_size=plan_size, function_selection="best",
             num_shards=plan_shards, backend=backend,
-            substrate_dtype=substrate_dtype,
+            substrate_dtype=substrate_dtype, pallas_interpret=pallas_interpret,
         ),
         max_capacity=max_capacity,
     )
@@ -387,6 +437,8 @@ def build_cascade_session_server(
     plan_shards: int = 1,
     backend: str = "jnp",
     substrate_dtype: str = "float32",
+    backbone_size: str = "smoke",
+    pallas_interpret: bool = False,
 ):
     """Long-lived serving session whose enrichment is the REAL model-cascade
     bank, traced into the fused scan superstep (``EngineSession(bank=...)``).
@@ -400,7 +452,8 @@ def build_cascade_session_server(
     -> (session, state, preds, qualities)
     """
     preds, evalc, bank, combine, table, qualities = _offline_phase(
-        num_objects, num_preds, backbone_arch, seed
+        num_objects, num_preds, backbone_arch, seed,
+        backbone_size=backbone_size,
     )
     session = EngineSession(
         [p.positive() for p in preds], table, combine, bank.costs,
@@ -408,7 +461,7 @@ def build_cascade_session_server(
         config=MultiQueryConfig(
             plan_size=plan_size, function_selection="best",
             num_shards=plan_shards, backend=backend,
-            substrate_dtype=substrate_dtype,
+            substrate_dtype=substrate_dtype, pallas_interpret=pallas_interpret,
         ),
         bank=bank,
     )
@@ -558,6 +611,9 @@ class SessionServeReport:
     substrate_dtype: str = "float32"  # storage dtype of the session substrate
     ring_drains: int = 0  # times the ring flushed into the session
     ingest_counters: dict = dataclasses.field(default_factory=dict)
+    # executed (object, predicate) triples per tagging function, live rows
+    # only: which cascade levels the planner actually bought
+    executed_per_function: list = dataclasses.field(default_factory=list)
 
 
 HOST_META_FORMAT = 1  # driver-shadow block version inside extra["host"]
@@ -867,6 +923,11 @@ def serve_session_trace(
         substrate_dtype=session.config.substrate_dtype,
         ring_drains=0 if streaming is None else streaming.drains,
         ingest_counters={} if streaming is None else streaming.counters(),
+        executed_per_function=[
+            int(c) for c in np.asarray(
+                state.substrate.exec_mask[:num_rows]
+            ).sum(axis=(0, 1))
+        ],
     )
 
 
@@ -876,6 +937,14 @@ def main(argv=None):
     ap.add_argument("--preds", type=int, default=1)
     ap.add_argument("--epochs", type=int, default=40)
     ap.add_argument("--backbone", default="qwen3-1.7b")
+    ap.add_argument("--backbone-size", default="smoke",
+                    choices=("smoke", "published"),
+                    help="cascade backbone at its reduced smoke config or at "
+                         "the architecture's published widths and depth "
+                         "(weights random from --seed)")
+    ap.add_argument("--seed", type=int, default=0,
+                    help="seed of the corpus, the cascade weights and the "
+                         "trace's admit draws")
     ap.add_argument("--queries", type=int, default=1,
                     help=">1 serves Q concurrent queries over one shared substrate")
     ap.add_argument("--preds-per-query", type=int, default=2)
@@ -884,6 +953,10 @@ def main(argv=None):
                          "shards (byte-identical to unsharded planning)")
     ap.add_argument("--backend", default="jnp", choices=("jnp", "pallas"),
                     help="benefit-scoring backend for the multi-tenant engine")
+    ap.add_argument("--pallas-interpret", action="store_true",
+                    help="run the Pallas kernels in the interpreter (hosts "
+                         "without a TPU); by default they compile for the "
+                         "device")
     ap.add_argument("--session", action="store_true",
                     help="serve a long-lived EngineSession driven by a "
                          "scripted ingest/admit/retire arrival trace")
@@ -974,6 +1047,7 @@ def main(argv=None):
                          "kill-and-resume job's bitwise diff surface)")
     args = ap.parse_args(argv)
 
+    use_compile_cache()
     handler = PreemptionHandler().install()
     if args.session:
         if args.bank == "cascade":
@@ -984,9 +1058,11 @@ def main(argv=None):
                 ap.error("--bank cascade is not wired into --supervise yet")
             session, state, preds, qualities = build_cascade_session_server(
                 num_objects=args.objects, num_preds=max(args.preds, 2),
-                max_tenants=args.max_tenants, backbone_arch=args.backbone,
+                max_tenants=args.max_tenants, seed=args.seed,
+                backbone_arch=args.backbone, backbone_size=args.backbone_size,
                 plan_shards=args.plan_shards, backend=args.backend,
                 substrate_dtype=args.substrate_dtype,
+                pallas_interpret=args.pallas_interpret,
             )
             pool = None
             print(f"[serve] cascade qualities (AUC): {qualities}")
@@ -994,9 +1070,10 @@ def main(argv=None):
             session, state, pool, preds = build_session_server(
                 num_objects=args.objects, capacity=args.capacity,
                 num_preds=max(args.preds, 2), max_tenants=args.max_tenants,
-                plan_shards=args.plan_shards, backend=args.backend,
-                max_capacity=args.max_capacity,
+                seed=args.seed, plan_shards=args.plan_shards,
+                backend=args.backend, max_capacity=args.max_capacity,
                 substrate_dtype=args.substrate_dtype,
+                pallas_interpret=args.pallas_interpret,
             )
         streaming = None
         if args.ingest_batch is not None:
@@ -1090,7 +1167,7 @@ def main(argv=None):
         else:
             report = serve_session_trace(
                 session, state, events, pool=pool, preds=preds,
-                preemption=handler, overlap=args.overlap,
+                seed=args.seed, preemption=handler, overlap=args.overlap,
                 chunk_size=args.chunk_size,
                 checkpointer=checkpointer, resume=resume,
                 streaming=streaming,
@@ -1105,7 +1182,7 @@ def main(argv=None):
             f"{report.num_rows} rows (tier {report.capacity} of "
             f"{report.max_capacity} max, {report.growths} growths), "
             f"{report.active_tenants} active tenants, "
-            f"cost={report.cost_spent:.4f}s-model, "
+            f"cost={report.cost_spent:.4f} (planner units), "
             f"mean E(F1)={report.mean_expected_f:.3f}, "
             f"ledger={bills} (+{report.unattributed:.4f} unattributed), "
             f"superstep traces={report.superstep_traces}, "
@@ -1140,9 +1217,21 @@ def main(argv=None):
                     "checkpoint_saves", "active_tenants", "mean_expected_f",
                     "quarantined", "degraded",
                     "streaming", "substrate_dtype", "ring_drains",
-                    "ingest_counters",
+                    "ingest_counters", "executed_per_function", "wall_s",
                 )
             }
+            cfg = backbone_config(args.backbone, args.backbone_size)
+            if args.bank == "cascade" and cfg is not None:
+                payload["backbone"] = dict(
+                    name=cfg.name, num_layers=cfg.num_layers,
+                    d_model=cfg.d_model, num_heads=cfg.num_heads,
+                    num_kv_heads=cfg.num_kv_heads, d_ff=cfg.d_ff,
+                )
+            payload["function_costs"] = np.asarray(session.program.costs).tolist()
+            # the bank's arrays reach the compiled superstep as arguments
+            payload["bank_param_bytes"] = sum(
+                x.nbytes for x in jax.tree.leaves(session.program.bank_params)
+            )
             if supervision is not None:
                 payload["supervision"] = supervision
             with open(args.report, "w") as fh:
@@ -1167,8 +1256,10 @@ def main(argv=None):
     if args.queries > 1:
         engine, corpus, truths, qualities, queries = build_multi_server(
             args.objects, args.preds, args.queries, args.backbone,
-            preds_per_query=args.preds_per_query,
+            seed=args.seed, preds_per_query=args.preds_per_query,
             plan_shards=args.plan_shards, backend=args.backend,
+            backbone_size=args.backbone_size,
+            pallas_interpret=args.pallas_interpret,
         )
         print(f"[serve] cascade qualities (AUC): {qualities}")
         report = serve_queries(engine, args.objects, args.epochs, handler)
@@ -1176,7 +1267,7 @@ def main(argv=None):
         eps = report.epochs / max(report.wall_s, 1e-9)
         print(
             f"[serve] {report.num_queries} queries x {report.epochs} epochs, "
-            f"cost={report.cost_spent:.4f}s-model "
+            f"cost={report.cost_spent:.4f} (planner units) "
             f"(requested {report.requested_cost:.4f}, dedup saved "
             f"{report.dedup_savings:.4f}), mean E(F1)={report.mean_expected_f:.3f}, "
             f"per-query E(F1)={[f'{x:.3f}' for x in report.expected_f]}, "
@@ -1185,13 +1276,14 @@ def main(argv=None):
         return 0
 
     op, corpus, truth, qualities = build_server(
-        args.objects, args.preds, args.backbone
+        args.objects, args.preds, args.backbone, seed=args.seed,
+        backbone_size=args.backbone_size,
     )
     print(f"[serve] cascade qualities (AUC): {qualities}")
     report = serve_query(op, args.objects, args.epochs, handler)
     eps = report.epochs / max(report.wall_s, 1e-9)
     print(
-        f"[serve] {report.epochs} epochs, cost={report.cost_spent:.4f}s-model, "
+        f"[serve] {report.epochs} epochs, cost={report.cost_spent:.4f} (planner units), "
         f"E(F1)={report.expected_f:.3f}, true F1={report.true_f1:.3f}, "
         f"wall={report.wall_s:.1f}s ({eps:.2f} epochs/s)"
     )

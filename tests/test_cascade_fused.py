@@ -324,3 +324,24 @@ def test_backbone_stack_requires_shared_trunk():
     feats = jax.random.normal(jax.random.PRNGKey(1), (8, FEATURE_DIM))
     with pytest.raises(ValueError, match="shared trunk"):
         ModelCascadeBank(cascades=cascades, features=feats)
+
+
+def test_superstep_takes_bank_arrays_as_arguments():
+    """The trunk, heads, probes and features reach the compiled scan as jit
+    ARGUMENTS.  Closed over, they would be baked into every executable as
+    constants (gigabytes at published widths) and left out of its
+    arguments."""
+    from repro.launch.serve import build_cascade_session_server
+
+    session, state, _, _ = build_cascade_session_server(
+        num_objects=32, num_preds=2, max_tenants=2,
+        backbone_arch="qwen3-1.7b", plan_size=8,
+    )
+    prog = session.program
+    params = prog.bank_params
+    trunk = params["levels"][-1]["trunk"]
+    nbytes = lambda t: sum(x.nbytes for x in jax.tree.leaves(t))
+    fn = prog._get_scan_fn(state.capacity, 2, False, False)
+    mem = fn.lower(state, params).compile().memory_analysis()
+    assert nbytes(trunk) > 0
+    assert mem.argument_size_in_bytes >= nbytes(state) + nbytes(params)

@@ -142,3 +142,76 @@ def test_benefit_finite_and_nonnegative(seed):
     fin = np.isfinite(b)
     assert np.all(b[fin] >= 0.0)
     assert np.all(np.asarray(out.est_joint) <= 1.0 + 1e-6)
+
+
+@pytest.mark.parametrize("mode", ["table", "best"])
+def test_batched_reference_recomputes_entropy_like_single_query(mode):
+    """The batched reference adds the table's delta to H(pred_prob)
+    recomputed in f32, as ``compute_benefits`` does; the stored uncertainty
+    (rounded on a bf16 substrate) only picks the bin.  So with a bf16-rounded
+    uncertainty the two paths still agree bit for bit."""
+    from repro.core.benefit import compute_benefits_batched
+
+    stt, query, _ = _mk_state(seed=3, n=96, p=2, f=4)
+    unc_bf16 = stt.uncertainty.astype(jnp.bfloat16).astype(jnp.float32)
+    assert (np.asarray(unc_bf16) != np.asarray(stt.uncertainty)).any()
+    stt = dataclasses.replace(stt, uncertainty=unc_bf16)
+    table = fallback_decision_table(2, 4, jnp.asarray([0.6, 0.7, 0.8, 0.9]))
+    costs = jnp.asarray(np.tile([0.02, 0.1, 0.4, 0.9], (2, 1)), jnp.float32)
+    one = compute_benefits(stt, query, table, costs, function_selection=mode,
+                           candidate_mask=jnp.ones((96,), bool))
+    batched = compute_benefits_batched(
+        stt.pred_prob, stt.uncertainty, stt.state_id(), stt.joint_prob[None],
+        table, costs, function_selection=mode,
+    )
+    valid = np.asarray(one.next_fn) >= 0
+    assert valid.any()
+    np.testing.assert_array_equal(np.asarray(batched.next_fn[0]), np.asarray(one.next_fn))
+    for name in ("benefit", "est_joint", "cost"):
+        np.testing.assert_array_equal(
+            np.asarray(getattr(batched, name)[0])[valid],
+            np.asarray(getattr(one, name))[valid],
+        )
+
+
+def test_batched_best_mode_f32_unchanged_by_running_argmax():
+    """Best mode's running max over F gives, at f32, exactly what the dense
+    [Q, N, P, F] argmax formulation gives."""
+    from repro.core.benefit import compute_benefits_batched, estimate_pred_prob_after
+    from repro.core.query import conjunctive_joint_update
+
+    p, f, q = 2, 4, 3
+    stt, _, _ = _mk_state(seed=5, n=80, p=p, f=f)
+    joint = jnp.asarray(np.random.default_rng(6).uniform(0.01, 1.0, (q, 80)), jnp.float32)
+    table = fallback_decision_table(p, f, jnp.asarray([0.6, 0.7, 0.8, 0.9]))
+    costs = jnp.asarray(np.tile([0.02, 0.1, 0.4, 0.9], (p, 1)), jnp.float32)
+    out = compute_benefits_batched(
+        stt.pred_prob, stt.uncertainty, stt.state_id(), joint, table, costs,
+        function_selection="best",
+    )
+    pred_idx = jnp.broadcast_to(jnp.arange(p)[None], (80, p))
+    dh_all = table.lookup_all(pred_idx, stt.state_id(), stt.uncertainty)
+    _, p_hat = estimate_pred_prob_after(
+        stt.pred_prob[..., None], jnp.where(jnp.isfinite(dh_all), dh_all, 0.0)
+    )
+    cost = jnp.maximum(jnp.broadcast_to(costs[None], dh_all.shape), 1e-9)
+    est = jnp.clip(
+        conjunctive_joint_update(
+            joint[:, :, None, None], stt.pred_prob[None, :, :, None], p_hat[None]
+        ),
+        0.0, 1.0,
+    )  # [Q, N, P, F]
+    ben = jnp.where(jnp.isfinite(dh_all)[None],
+                    joint[:, :, None, None] * est / cost[None], -1e30)
+    nf = np.asarray(jnp.argmax(ben, axis=-1))
+    valid = np.isfinite(np.asarray(dh_all)).any(-1)[None].repeat(q, 0)
+    assert valid.any() and not valid.all()
+    np.testing.assert_array_equal(np.asarray(out.next_fn)[valid], nf[valid])
+    np.testing.assert_array_equal(np.asarray(out.next_fn)[~valid], -1)
+    pick = lambda x: np.take_along_axis(np.asarray(x), nf[..., None], -1)[..., 0]
+    np.testing.assert_array_equal(np.asarray(out.benefit)[valid], pick(ben)[valid])
+    np.testing.assert_array_equal(np.asarray(out.est_joint)[valid], pick(est)[valid])
+    np.testing.assert_array_equal(
+        np.asarray(out.cost)[valid],
+        pick(jnp.broadcast_to(cost[None], ben.shape))[valid],
+    )

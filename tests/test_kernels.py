@@ -352,6 +352,90 @@ def test_enrich_score_batched_edge_bins(mode):
     assert (np.asarray(out.next_fn)[:, 2 * n // 3 :, :] == -1).all()
 
 
+@pytest.mark.parametrize("mode", ["table", "best"])
+def test_enrich_score_batched_jitted_with_constant_tables(mode):
+    """Inside a jitted program (how the session superstep calls it) the
+    decision table and costs are compile-time constants.  The kernel must
+    give exactly what the same program gives with them passed as arguments,
+    and stay within KERNEL_RTOL of the reference: a table staged through
+    XLA's constant folding once came out with entries permuted."""
+    from repro.core.benefit import compute_benefits_batched
+    from repro.core.entropy import binary_entropy
+    from repro.kernels.enrich_score.kernel import KERNEL_RTOL
+
+    p, f, n, q = 4, 4, 1024, 3
+    table = fallback_decision_table(p, f, jnp.full((p, f), 0.85), num_bins=10)
+    rng = np.random.default_rng(0)
+    costs = jnp.asarray(rng.uniform(0.05, 1.0, (p, f)), jnp.float32)
+    pp = jnp.asarray(rng.uniform(0.01, 0.99, (n, p)), jnp.float32)
+    sid = jnp.asarray(rng.integers(0, 2 ** f, (n, p)), jnp.int32)
+    joint = jnp.asarray(rng.uniform(0.0, 1.0, (q, n)), jnp.float32)
+    args = (pp, binary_entropy(pp), sid, joint)
+
+    def fused(*a):
+        return es_ops.fused_benefits_batched(
+            *a, table, costs, function_selection=mode, interpret=True
+        )
+
+    def fused_args(tab, cst, *a):
+        return es_ops.fused_benefits_batched(
+            *a, tab, cst, function_selection=mode, interpret=True
+        )
+
+    staged_at_run = jax.jit(fused_args)(table, costs, *args)
+    jitted = jax.jit(fused)(*args)
+    for name in staged_at_run._fields:
+        np.testing.assert_array_equal(
+            np.asarray(getattr(jitted, name)),
+            np.asarray(getattr(staged_at_run, name)),
+        )
+    ref = compute_benefits_batched(*args, table, costs, function_selection=mode)
+    valid = np.asarray(ref.next_fn) >= 0
+    np.testing.assert_array_equal(np.asarray(jitted.next_fn), np.asarray(ref.next_fn))
+    for name in ("benefit", "est_joint"):
+        np.testing.assert_allclose(
+            np.asarray(getattr(jitted, name))[valid],
+            np.asarray(getattr(ref, name))[valid],
+            rtol=KERNEL_RTOL, atol=KERNEL_RTOL,
+        )
+
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("mode", ["table", "best"])
+def test_enrich_score_batched_bitwise_with_reference_under_jit(mode, dtype):
+    """As the session superstep runs them (jitted, table and costs closed
+    over), the interpreted kernel and the jnp reference agree bit for bit on
+    every output of every lane that has a next function."""
+    from repro.core.benefit import compute_benefits_batched
+    from repro.core.entropy import binary_entropy
+
+    p, f, n, q = 4, 4, 1024, 3
+    dt = jnp.dtype(dtype)
+    table = fallback_decision_table(p, f, jnp.full((p, f), 0.85), num_bins=10)
+    rng = np.random.default_rng(1)
+    costs = jnp.asarray(rng.uniform(0.05, 1.0, (p, f)), jnp.float32)
+    pp = jnp.asarray(rng.uniform(0.01, 0.99, (n, p)), jnp.float32).astype(dt)
+    unc = binary_entropy(pp.astype(jnp.float32)).astype(dt)
+    sid = jnp.asarray(rng.integers(0, 2 ** f, (n, p)), jnp.int32)
+    joint = jnp.asarray(rng.uniform(0.0, 1.0, (q, n)), jnp.float32).astype(dt)
+
+    out = jax.jit(lambda *a: es_ops.fused_benefits_batched(
+        *a, table, costs, function_selection=mode, interpret=True
+    ))(pp, unc, sid, joint)
+    ref = jax.jit(lambda *a: compute_benefits_batched(
+        *(x if x.dtype == jnp.int32 else x.astype(jnp.float32) for x in a),
+        table, costs, function_selection=mode,
+    ))(pp, unc, sid, joint)
+    valid = np.asarray(ref.next_fn) >= 0
+    assert valid.any() and not valid.all()
+    np.testing.assert_array_equal(np.asarray(out.next_fn), np.asarray(ref.next_fn))
+    for name in ("benefit", "est_joint", "cost"):
+        np.testing.assert_array_equal(
+            np.asarray(getattr(out, name))[valid], np.asarray(getattr(ref, name))[valid]
+        )
+
+
 def test_enrich_score_batched_with_learned_table():
     from repro.data.synthetic import make_corpus
 

@@ -16,7 +16,7 @@ import pytest
 
 from repro.configs.archs import ARCHS, get_config
 from repro.configs.shapes import SHAPES, ShapeSpec, all_cells, shape_applicable
-from repro.launch.mesh import make_host_mesh
+from repro.launch.mesh import make_host_mesh, make_mesh
 from repro.launch.rules import rules_for_cell
 
 
@@ -39,9 +39,7 @@ def test_long500k_applicability_matches_design():
 
 
 def test_rules_divisibility_fallbacks():
-    import jax as _jax
-
-    mesh = _jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
 
     class FakeMesh:
         axis_names = ("data", "model")
@@ -93,7 +91,8 @@ def test_small_mesh_lower_compile(shape_name):
         from repro.configs.archs import get_config
         from repro.configs.shapes import SHAPES
         from repro.launch.steps import build_step
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4, 2), ("data", "model"))
         spec = dataclasses.replace(
             SHAPES["{shape_name}"],
             seq_len=128 if "{shape_name}" != "train_4k" else 64,

@@ -32,6 +32,7 @@ def test_sharded_superstep_on_two_device_mesh_matches_single_device():
         from repro.core import state as state_lib
         from repro.core.combine import default_combine_params
         from repro.data.synthetic import make_corpus
+        from repro.launch.mesh import make_mesh
 
         assert jax.device_count() == 2, jax.devices()
         P, F, N = 4, 4, 128
@@ -51,7 +52,7 @@ def test_sharded_superstep_on_two_device_mesh_matches_single_device():
             )
             st = sess.init_state(corpus.func_probs[:64])
             if place_on_mesh:
-                mesh = jax.make_mesh((2,), ("data",))
+                mesh = make_mesh((2,), ("data",))
                 st = dataclasses.replace(
                     st, substrate=state_lib.shard_substrate(st.substrate, mesh)
                 )

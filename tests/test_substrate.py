@@ -92,12 +92,13 @@ def test_elastic_restore_different_mesh(tmp_path):
         from repro.checkpoint.store import save_checkpoint, restore_checkpoint
 
         tree = {"w": jnp.arange(64, dtype=jnp.float32).reshape(8, 8)}
-        mesh4 = jax.make_mesh((4,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh4 = make_mesh((4,), ("data",))
         sh4 = {"w": NamedSharding(mesh4, P("data"))}
         placed = jax.device_put(tree["w"], sh4["w"])
         save_checkpoint("CKPT", 3, {"w": placed})
 
-        mesh2 = jax.make_mesh((2,), ("data",))
+        mesh2 = make_mesh((2,), ("data",))
         sh2 = {"w": NamedSharding(mesh2, P("data"))}
         restored, step = restore_checkpoint("CKPT", None, tree, sh2)
         assert step == 3

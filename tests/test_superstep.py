@@ -214,7 +214,7 @@ def test_engine_pallas_backend_matches_jnp(function_selection):
     eng_j = _engine(_queries(preds), preds, bank, combine, table,
                     backend="jnp", **kw)
     eng_p = _engine(_queries(preds), preds, bank, combine, table,
-                    backend="pallas", **kw)
+                    backend="pallas", pallas_interpret=True, **kw)
     s_j, h_j = eng_j.run_scan(N, 3)
     s_p, h_p = eng_p.run_scan(N, 3)
     assert len(h_j) == len(h_p)
