@@ -1,0 +1,1 @@
+"""metrics of the PIQUE benchmark, each found by name."""

@@ -1,0 +1,1 @@
+"""reference of the PIQUE benchmark, each found by name."""
