@@ -17,7 +17,6 @@ answer-selection machinery so the comparison isolates scheduling policy.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Optional
 
 import jax
@@ -172,7 +171,6 @@ class StaticOrderEvaluator:
         for e in range(num_epochs):
             if offset >= total:
                 break
-            t0 = time.perf_counter()
             plan = plan_lib.static_plan_from_order(
                 order_j, preds_j, fns_j, self.costs,
                 jnp.asarray(offset, jnp.int32), self.config.plan_size,
@@ -200,7 +198,6 @@ class StaticOrderEvaluator:
                     true_f1=tf1,
                     plan_cost=float(plan.total_cost()),
                     plan_valid=int(plan.num_valid()),
-                    wall_time_s=time.perf_counter() - t0,
                 )
             )
         return st, history

@@ -39,7 +39,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import time
 import warnings
 from typing import Optional
 
@@ -52,6 +51,7 @@ from repro.core import ledger as ledger_lib
 from repro.core import plan as plan_lib
 from repro.core import state as state_lib
 from repro.core import threshold as threshold_lib
+from repro.core import tracing
 from repro.core.benefit import NEG_INF, TripleBenefits
 from repro.core.combine import combine_probabilities
 from repro.core.entropy import binary_entropy
@@ -254,7 +254,7 @@ class SessionEpochStats:
     active: list  # [S] bool snapshot
     num_rows: int
     attributed: list  # [S] cumulative ledger attribution snapshot
-    wall_time_s: float
+    level_lanes: list  # [F] valid merged lanes per function level
     answer_mask: Optional[np.ndarray] = None  # [S, C] when collect_masks
     true_f: Optional[list] = None  # [S] when the program carries truth_masks
 
@@ -317,7 +317,15 @@ class EpochProgram:
         self.truth_masks = None if truth_masks is None else jnp.asarray(truth_masks)
         self._trace_count = 0  # superstep (re)traces
         self._scan_cache: dict = {}
-        self._refresh_fn = jax.jit(self._refresh)
+        # a jitted function's name is its module's name, which keys JAX's
+        # persistent compilation cache beside the computation, while op
+        # metadata does not: a program whose scopes change and whose
+        # computation does not takes a new name, or the cache serves it an
+        # executable that carries the old names (see compiled_hlo)
+        self._refresh_fn = jax.jit(self._refresh_program)
+        # argument shapes of each program's first dispatch, for compiled_hlo
+        self._scan_args: dict = {}  # scan-cache key -> (state, bank_params)
+        self._refresh_args: dict = {}  # capacity -> (state,)
 
     @property
     def num_predicates(self) -> int:
@@ -355,36 +363,37 @@ class EpochProgram:
         so this path is bitwise-identical to the pre-dtype-knob executor.
         """
         store_dt = substrate.func_probs.dtype
-        pred32 = combine_probabilities(
-            self.combine_params,
-            substrate.func_probs.astype(jnp.float32),
-            substrate.exec_mask,
-            prior=self.config.prior,
-        )  # [C, P] f32
-        joint32 = jnp.prod(
-            jnp.where(pred_mask[:, None, :], pred32[None], 1.0), axis=-1
-        )  # [S, C] f32
-        joint32 = jnp.where(active[:, None] & row_valid[None, :], joint32, 0.0)
-        return (
-            pred32.astype(store_dt),
-            binary_entropy(pred32).astype(store_dt),
-            joint32.astype(store_dt),
-        )
+        with tracing.scope(tracing.DERIVE):
+            pred32 = combine_probabilities(
+                self.combine_params,
+                substrate.func_probs.astype(jnp.float32),
+                substrate.exec_mask,
+                prior=self.config.prior,
+            )  # [C, P] f32
+            joint32 = jnp.prod(
+                jnp.where(pred_mask[:, None, :], pred32[None], 1.0), axis=-1
+            )  # [S, C] f32
+            joint32 = jnp.where(active[:, None] & row_valid[None, :], joint32, 0.0)
+            return (
+                pred32.astype(store_dt),
+                binary_entropy(pred32).astype(store_dt),
+                joint32.astype(store_dt),
+            )
 
     def _select_answers(self, joint_prob: jax.Array) -> threshold_lib.AnswerSelection:
         # Selection consumes the STORED joint (upcast exactly to f32), so
         # answer membership is always derivable from a checkpointed state
         # regardless of the storage dtype; no-op under the f32 default.
-        joint_prob = joint_prob.astype(jnp.float32)
         if self.config.answer_mode == "approx":
             fn = functools.partial(
                 threshold_lib.select_answer_approx, alpha=self.config.alpha
             )
         else:
             fn = functools.partial(threshold_lib.select_answer, alpha=self.config.alpha)
-        return jax.vmap(fn)(joint_prob)
+        with tracing.scope(tracing.SELECT):
+            return jax.vmap(fn)(joint_prob.astype(jnp.float32))
 
-    def _refresh(self, state: SessionState) -> SessionState:
+    def _refresh_program(self, state: SessionState) -> SessionState:
         """Recompute all derived state from the substrate + masks.
 
         The warm-start path for every churn event: an admitted slot's first
@@ -392,20 +401,24 @@ class EpochProgram:
         accumulated (paper §5 caching), ingested rows surface with cold prior
         state, retired slots drop out of answers.
         """
-        row_valid = state.row_valid()
-        pp, unc, joint = self._derive(
-            state.substrate, state.pred_mask, state.active, row_valid
-        )
-        sel = self._select_answers(joint)
-        mask = sel.mask & state.active[:, None] & row_valid[None, :]
-        derived = SessionDerived(
-            pred_prob=pp, uncertainty=unc, joint_prob=joint, in_answer=mask
-        )
-        return dataclasses.replace(state, derived=derived)
+        with tracing.scope(tracing.REFRESH):
+            row_valid = state.row_valid()
+            pp, unc, joint = self._derive(
+                state.substrate, state.pred_mask, state.active, row_valid
+            )
+            sel = self._select_answers(joint)
+            mask = sel.mask & state.active[:, None] & row_valid[None, :]
+            derived = SessionDerived(
+                pred_prob=pp, uncertainty=unc, joint_prob=joint, in_answer=mask
+            )
+            return dataclasses.replace(state, derived=derived)
 
     def refresh(self, state: SessionState) -> SessionState:
         """Jitted public entry for state-adoption paths."""
-        return self._refresh_fn(state)
+        with tracing.span(tracing.REFRESH):
+            if state.capacity not in self._refresh_args:
+                self._refresh_args[state.capacity] = _abstract((state,))
+            return self._refresh_fn(state)
 
     # ---- scoring + planning ------------------------------------------------
 
@@ -415,59 +428,62 @@ class EpochProgram:
         never win top-k."""
         cfg = self.config
         der = state.derived
-        state_id = state.substrate.state_id()  # [C, P]
-        if state.quarantined is not None:
-            # quarantined functions look "already executed" to the table
-            # lookup (both modes, both backends route through state_id), so
-            # they can never be planned; pred_prob is untouched — enrichment
-            # already applied keeps contributing to answers.
-            state_id = state_id | state_lib.pack_function_bits(state.quarantined)[None, :]
         mode = (
             "best"
             if cfg.function_selection == "best" and self.table.delta_h_all is not None
             else "table"
         )
-        if cfg.backend == "pallas":
-            from repro.kernels.enrich_score import ops as es_ops
+        with tracing.scope(tracing.SCORE):
+            state_id = state.substrate.state_id()  # [C, P]
+            if state.quarantined is not None:
+                # quarantined functions look "already executed" to the table
+                # lookup (both modes, both backends route through state_id),
+                # so they can never be planned; pred_prob is untouched —
+                # enrichment already applied keeps contributing to answers.
+                state_id = state_id | state_lib.pack_function_bits(state.quarantined)[None, :]
+            if cfg.backend == "pallas":
+                from repro.kernels.enrich_score import ops as es_ops
 
-            # raw storage dtype straight into the kernel: bf16 rows are
-            # upcast to f32 in-register inside each tile (dequant-in-tile),
-            # so no f32 copy of the substrate-derived rows ever hits HBM.
-            tb = es_ops.fused_benefits_batched(
-                der.pred_prob, der.uncertainty, state_id,
-                der.joint_prob, self.table, self.costs,
-                function_selection=mode,
-                interpret=cfg.pallas_interpret,
+                # raw storage dtype straight into the kernel: bf16 rows are
+                # upcast to f32 in-register inside each tile (dequant-in-
+                # tile), so no f32 copy of the substrate-derived rows ever
+                # hits HBM.
+                tb = es_ops.fused_benefits_batched(
+                    der.pred_prob, der.uncertainty, state_id,
+                    der.joint_prob, self.table, self.costs,
+                    function_selection=mode,
+                    interpret=cfg.pallas_interpret,
+                )
+            else:
+                # the jnp backend has no tile boundary to hide the upcast
+                # in; dequantize at the input (exact, no-op under f32)
+                tb = benefit_lib.compute_benefits_batched(
+                    der.pred_prob.astype(jnp.float32),
+                    der.uncertainty.astype(jnp.float32),
+                    state_id,
+                    der.joint_prob.astype(jnp.float32),
+                    self.table, self.costs,
+                    function_selection=mode,
+                )
+            benefit, nf, est_joint, cost = tb
+            valid = (
+                (nf >= 0)
+                & state.pred_mask[:, None, :]
+                & state.active[:, None, None]
+                & row_valid[None, :, None]
             )
-        else:
-            # the jnp backend has no tile boundary to hide the upcast in;
-            # dequantize at the input (exact, no-op under f32)
-            tb = benefit_lib.compute_benefits_batched(
-                der.pred_prob.astype(jnp.float32),
-                der.uncertainty.astype(jnp.float32),
-                state_id,
-                der.joint_prob.astype(jnp.float32),
-                self.table, self.costs,
-                function_selection=mode,
-            )
-        benefit, nf, est_joint, cost = tb
-        valid = (
-            (nf >= 0)
-            & state.pred_mask[:, None, :]
-            & state.active[:, None, None]
-            & row_valid[None, :, None]
-        )
-        benefit = jnp.where(valid, benefit, NEG_INF)
-        unc32 = der.uncertainty.astype(jnp.float32)
-        cand = jax.vmap(
-            lambda a, m: benefit_lib.candidate_mask(
-                unc32, a, cfg.candidate_strategy,
-                pred_mask=m, row_valid=row_valid,
-            )
-        )(der.in_answer, state.pred_mask)  # [S, C]
-        benefit = jax.vmap(
-            lambda b, c: benefit_lib.restrict_benefits(b, c, cfg.plan_size)
-        )(benefit, cand)
+            benefit = jnp.where(valid, benefit, NEG_INF)
+        with tracing.scope(tracing.CANDIDATES):
+            unc32 = der.uncertainty.astype(jnp.float32)
+            cand = jax.vmap(
+                lambda a, m: benefit_lib.candidate_mask(
+                    unc32, a, cfg.candidate_strategy,
+                    pred_mask=m, row_valid=row_valid,
+                )
+            )(der.in_answer, state.pred_mask)  # [S, C]
+            benefit = jax.vmap(
+                lambda b, c: benefit_lib.restrict_benefits(b, c, cfg.plan_size)
+            )(benefit, cand)
         return TripleBenefits(benefit=benefit, next_fn=nf, est_joint=est_joint, cost=cost)
 
     def _plan_part(self, state: SessionState):
@@ -475,27 +491,29 @@ class EpochProgram:
         cfg = self.config
         row_valid = state.row_valid()
         benefits = self._benefits(state, row_valid)
-        plans = select_plans_batched(
-            benefits,
-            plan_size=cfg.plan_size,
-            num_shards=cfg.num_shards,
-            num_predicates=self.num_predicates,
-        )
-        merged, want_bits = plan_lib.merge_plans_dedup_wants(
-            plans,
-            self.num_predicates,
-            self.num_functions,
-            num_slots=state.num_slots,
-            capacity=cfg.merged_capacity,
-            cost_budget=cfg.epoch_cost_budget,
-            num_objects=state.capacity,
-        )
-        if state.quarantined is not None:
-            # defense in depth: even if a quarantined lane survived scoring
-            # (it cannot, by the state-id OR above), it must neither execute
-            # nor bill — apply and ledger attribution both key off
-            # ``merged.valid``.
-            merged = plan_lib.quarantine_filter(merged, state.quarantined)
+        with tracing.scope(tracing.TOPK):
+            plans = select_plans_batched(
+                benefits,
+                plan_size=cfg.plan_size,
+                num_shards=cfg.num_shards,
+                num_predicates=self.num_predicates,
+            )
+        with tracing.scope(tracing.MERGE):
+            merged, want_bits = plan_lib.merge_plans_dedup_wants(
+                plans,
+                self.num_predicates,
+                self.num_functions,
+                num_slots=state.num_slots,
+                capacity=cfg.merged_capacity,
+                cost_budget=cfg.epoch_cost_budget,
+                num_objects=state.capacity,
+            )
+            if state.quarantined is not None:
+                # defense in depth: even if a quarantined lane survived
+                # scoring (it cannot, by the state-id OR above), it must
+                # neither execute nor bill — apply and ledger attribution
+                # both key off ``merged.valid``.
+                merged = plan_lib.quarantine_filter(merged, state.quarantined)
         return plans, merged, want_bits
 
     def _gather_outputs(
@@ -513,37 +531,41 @@ class EpochProgram:
         session fills) and stay inert: apply drops them, chargeable/want-bits
         are valid-masked.
         """
-        if self.bank is not None:
-            probs = self.bank.execute(merged, bank_params)
-            return probs.astype(state.substrate.func_probs.dtype)
-        obj = plan_lib.gather_object_idx(merged, state.capacity)
-        return state.bank_outputs[obj, merged.pred_idx, jnp.maximum(merged.func_idx, 0)]
+        with tracing.scope(tracing.BANK):
+            if self.bank is not None:
+                probs = self.bank.execute(merged, bank_params)
+                return probs.astype(state.substrate.func_probs.dtype)
+            obj = plan_lib.gather_object_idx(merged, state.capacity)
+            return state.bank_outputs[obj, merged.pred_idx, jnp.maximum(merged.func_idx, 0)]
 
     def _apply_part(self, state, plans, merged, want_bits, outputs):
         """The superstep past the bank boundary: charge, apply, attribute,
         re-derive, select.  Stats always carry the answer mask; drivers drop
         it when masks were not requested (dead code under jit)."""
         row_valid = state.row_valid()
-        # the SAME charging rule apply_outputs_to_substrate bills cost_spent
-        # with, so ledger attribution reconciles by construction
-        chargeable = state_lib.chargeable_mask(
-            state.substrate, merged.object_idx, merged.pred_idx,
-            merged.func_idx, merged.valid,
-        )
+        with tracing.scope(tracing.APPLY):
+            # the SAME charging rule apply_outputs_to_substrate bills
+            # cost_spent with, so ledger attribution reconciles by
+            # construction
+            chargeable = state_lib.chargeable_mask(
+                state.substrate, merged.object_idx, merged.pred_idx,
+                merged.func_idx, merged.valid,
+            )
+            sub = state_lib.apply_outputs_to_substrate(
+                state.substrate,
+                merged.object_idx,
+                merged.pred_idx,
+                merged.func_idx,
+                outputs,
+                merged.cost,
+                merged.valid,
+            )
+            ledger = ledger_lib.attribute_epoch(state.ledger, merged, want_bits, chargeable)
         prev_cost = state.substrate.cost_spent
-        sub = state_lib.apply_outputs_to_substrate(
-            state.substrate,
-            merged.object_idx,
-            merged.pred_idx,
-            merged.func_idx,
-            outputs,
-            merged.cost,
-            merged.valid,
-        )
-        ledger = ledger_lib.attribute_epoch(state.ledger, merged, want_bits, chargeable)
         pp, unc, joint = self._derive(sub, state.pred_mask, state.active, row_valid)
         sel = self._select_answers(joint)
-        mask = sel.mask & state.active[:, None] & row_valid[None, :]
+        with tracing.scope(tracing.SELECT):
+            mask = sel.mask & state.active[:, None] & row_valid[None, :]
         new_state = dataclasses.replace(
             state,
             substrate=sub,
@@ -560,6 +582,12 @@ class EpochProgram:
             answer_size=jnp.sum(mask, axis=1),
             plan_valid=jnp.sum(plans.valid, axis=1),
             merged_valid=merged.num_valid(),
+            # valid merged lanes per function level; sums to merged_valid
+            level_lanes=jnp.sum(
+                merged.valid[:, None]
+                & (merged.func_idx[:, None] == jnp.arange(self.num_functions)),
+                axis=0, dtype=jnp.int32,
+            ),
             active=state.active,
             num_rows=state.num_rows,
             attributed=ledger.attributed,
@@ -633,7 +661,36 @@ class EpochProgram:
         """Dispatch ONE scan chunk without blocking; returns state + stats
         futures.  The building block of the async event pipeline."""
         fn = self._get_scan_fn(state.capacity, length, collect_masks, donate)
-        return fn(state, self.bank_params)
+        key = (state.capacity, length, collect_masks, donate)
+        if key not in self._scan_args:
+            self._scan_args[key] = _abstract((state, self.bank_params))
+        with tracing.span(tracing.DISPATCH):
+            return fn(state, self.bank_params)
+
+    def compiled_hlo(self) -> list:
+        """-> ``[(kind, text)]``: the optimized HLO of every program this
+        executor has dispatched, ``kind`` "superstep" (one per scan shape)
+        or "refresh" (one per capacity).
+
+        Each is lowered again from the argument shapes of its first
+        dispatch; JAX's trace cache serves that without a retrace
+        (``superstep_traces`` does not move), and a configured persistent
+        compilation cache serves the compile.  A profiler trace names each
+        device op after an instruction of these programs, whose ``op_name``
+        metadata carries the ``pique/`` scopes (``core.tracing``).  That
+        cache keys a program by its computation and module name, never by
+        its metadata, so the text carries the names of whichever program
+        first compiled the same computation under the same name.
+        """
+        out = [
+            ("superstep", self._scan_cache[key].lower(*args).compile().as_text())
+            for key, args in self._scan_args.items()
+        ]
+        out += [
+            ("refresh", self._refresh_fn.lower(*args).compile().as_text())
+            for args in self._refresh_args.values()
+        ]
+        return out
 
     def run_scan(
         self,
@@ -668,71 +725,88 @@ class EpochProgram:
         """
         if chunk_size is None:
             chunk_size = self.config.chunk_size
-        t0 = time.perf_counter()
-        chunks = []
-        dispatched = 0
-        for length in self.chunk_lengths(num_epochs, chunk_size):
-            state, stats = self.dispatch_scan(
-                state, length, collect_masks, donate=donate
+        with tracing.span(tracing.RUN) as span:
+            chunks = []
+            dispatched = 0
+            for length in self.chunk_lengths(num_epochs, chunk_size):
+                state, stats = self.dispatch_scan(
+                    state, length, collect_masks, donate=donate
+                )
+                chunks.append((length, stats))
+                dispatched += length
+                if on_chunk is not None and on_chunk(state, dispatched):
+                    break
+            span.set_metadata(epochs=dispatched, traces=self.superstep_traces)
+            with tracing.span(tracing.WAIT):
+                hosts = [(length, jax.device_get(s)) for length, s in chunks]
+                state = jax.block_until_ready(state)
+            history = self.materialize_history(
+                hosts,
+                collect_masks=collect_masks,
+                stop_when_exhausted=stop_when_exhausted,
             )
-            chunks.append((length, stats))
-            dispatched += length
-            if on_chunk is not None and on_chunk(state, dispatched):
-                break
-        hosts = [(length, jax.device_get(s)) for length, s in chunks]
-        state = jax.block_until_ready(state)
-        wall = time.perf_counter() - t0
-        history = self.materialize_history(
-            hosts,
-            wall_per_epoch=wall / max(dispatched, 1),
-            collect_masks=collect_masks,
-            stop_when_exhausted=stop_when_exhausted,
-        )
         return state, history
 
     @staticmethod
     def materialize_history(
         hosts,  # [(chunk_len, host_stats_dict)] with leading [L] on leaves
-        wall_per_epoch: float,
         collect_masks: bool,
         stop_when_exhausted: bool,
         epoch_base: int = 0,
     ) -> list:
         """Build ``SessionEpochStats`` from chunked host-side scan stats,
-        trimming post-exhaustion no-op epochs to match the loop driver."""
-        history: list[SessionEpochStats] = []
-        e = epoch_base
-        for length, stats in hosts:
-            for i in range(length):
-                merged_valid = int(stats["merged_valid"][i])
-                history.append(
-                    SessionEpochStats(
-                        epoch=e,
-                        cost_spent=float(stats["cost_spent"][i]),
-                        epoch_cost=float(stats["epoch_cost"][i]),
-                        requested_cost=float(stats["requested_cost"][i]),
-                        expected_f=[float(x) for x in stats["expected_f"][i]],
-                        answer_size=[int(x) for x in stats["answer_size"][i]],
-                        plan_valid=[int(x) for x in stats["plan_valid"][i]],
-                        merged_valid=merged_valid,
-                        active=[bool(x) for x in stats["active"][i]],
-                        num_rows=int(stats["num_rows"][i]),
-                        attributed=[float(x) for x in stats["attributed"][i]],
-                        wall_time_s=wall_per_epoch,
-                        answer_mask=(
-                            np.asarray(stats["answer_mask"][i])
-                            if collect_masks
-                            else None
-                        ),
-                        true_f=(
-                            [float(x) for x in stats["true_f"][i]]
-                            if "true_f" in stats
-                            else None
-                        ),
+        trimming post-exhaustion no-op epochs to match the loop driver.
+        The ``pique.history`` span carries the merged lanes per function
+        level, summed over every epoch of ``hosts``."""
+        counts = {}
+        if hosts:
+            lanes = sum(np.sum(s["level_lanes"], axis=0) for _, s in hosts)
+            counts = {f"lanes_{i}": int(n) for i, n in enumerate(lanes)}
+        with tracing.span(tracing.HISTORY, **counts):
+            history: list[SessionEpochStats] = []
+            e = epoch_base
+            for length, stats in hosts:
+                for i in range(length):
+                    merged_valid = int(stats["merged_valid"][i])
+                    history.append(
+                        SessionEpochStats(
+                            epoch=e,
+                            cost_spent=float(stats["cost_spent"][i]),
+                            epoch_cost=float(stats["epoch_cost"][i]),
+                            requested_cost=float(stats["requested_cost"][i]),
+                            expected_f=[float(x) for x in stats["expected_f"][i]],
+                            answer_size=[int(x) for x in stats["answer_size"][i]],
+                            plan_valid=[int(x) for x in stats["plan_valid"][i]],
+                            merged_valid=merged_valid,
+                            active=[bool(x) for x in stats["active"][i]],
+                            num_rows=int(stats["num_rows"][i]),
+                            attributed=[float(x) for x in stats["attributed"][i]],
+                            level_lanes=[int(x) for x in stats["level_lanes"][i]],
+                            answer_mask=(
+                                np.asarray(stats["answer_mask"][i])
+                                if collect_masks
+                                else None
+                            ),
+                            true_f=(
+                                [float(x) for x in stats["true_f"][i]]
+                                if "true_f" in stats
+                                else None
+                            ),
+                        )
                     )
-                )
-                e += 1
-                if stop_when_exhausted and merged_valid == 0:
-                    return history
-        return history
+                    e += 1
+                    if stop_when_exhausted and merged_valid == 0:
+                        return history
+            return history
 
+
+def _abstract(args):
+    """Shapes, dtypes and placements of a dispatch's arguments (what
+    ``EpochProgram.compiled_hlo`` lowers again)."""
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=getattr(x, "sharding", None),
+            weak_type=getattr(x, "weak_type", False),
+        ) if hasattr(x, "shape") else x,
+        args,
+    )

@@ -213,7 +213,6 @@ class MultiEpochStats:
     true_f: Optional[list]  # [Q] against ground truth, when available
     plan_valid: list  # [Q] valid triples each query requested
     merged_valid: int  # unique triples actually executed
-    wall_time_s: float  # scan driver: total wall / epochs (amortized)
     answer_mask: Optional[np.ndarray] = None  # [Q, N] when collect_masks
 
     @property
@@ -369,7 +368,6 @@ class MultiQueryEngine:
                     true_f=tf,
                     plan_valid=h.plan_valid,
                     merged_valid=h.merged_valid,
-                    wall_time_s=h.wall_time_s,
                     answer_mask=h.answer_mask if collect_masks else None,
                 )
             )
@@ -684,7 +682,7 @@ class MultiQueryEngine:
         Non-conjunctive query sets fall back to the legacy per-epoch loop
         with identical results (general ASTs are outside the session's
         data-masked slot model).  Post-exhaustion epochs are no-ops trimmed
-        from the history; ``wall_time_s`` is the amortized total.
+        from the history.
         """
         created_here = state is None
         if state is None:
@@ -725,7 +723,7 @@ class MultiQueryEngine:
     ) -> tuple[MultiQueryState, list]:
         history: list[MultiEpochStats] = []
         for e in range(num_epochs):
-            state, sel, plans, merged, wall, prev_cost = self.run_epoch(state)
+            state, sel, plans, merged, _, prev_cost = self.run_epoch(state)
             tf = None
             if self.truth_masks is not None:
                 tf = [
@@ -746,7 +744,6 @@ class MultiQueryEngine:
                     true_f=tf,
                     plan_valid=[int(x) for x in jnp.sum(plans.valid, axis=1)],
                     merged_valid=merged_valid,
-                    wall_time_s=wall,
                     answer_mask=(
                         np.asarray(sel.mask) if collect_masks else None
                     ),

@@ -62,7 +62,6 @@ class EpochStats:
     true_f1: Optional[float]
     plan_cost: float
     plan_valid: int
-    wall_time_s: float
 
 
 class ProgressiveQueryOperator:
@@ -209,7 +208,6 @@ class ProgressiveQueryOperator:
                     true_f1=tf1,
                     plan_cost=h.epoch_cost,
                     plan_valid=h.merged_valid,
-                    wall_time_s=h.wall_time_s,
                 )
             )
         return out
@@ -314,8 +312,8 @@ class ProgressiveQueryOperator:
         ``EngineSession`` tenant at capacity == N; no per-epoch host syncs).
         Query shapes outside the session's scope (general ASTs, exact_slow,
         custom benefit_fn) fall back to the per-epoch loop with identical
-        results.  Post-exhaustion epochs are no-ops trimmed from the history;
-        ``wall_time_s`` is the amortized total."""
+        results.  Post-exhaustion epochs are no-ops trimmed from the
+        history."""
         created_here = state is None
         if state is None:
             state = self.init_state(num_objects)
@@ -340,7 +338,7 @@ class ProgressiveQueryOperator:
     ) -> tuple[state_lib.EnrichmentState, list[EpochStats]]:
         history: list[EpochStats] = []
         for e in range(num_epochs):
-            state, sel, plan, wall = self.run_epoch(state)
+            state, sel, plan, _ = self.run_epoch(state)
             tf1 = None
             if self.truth_mask is not None:
                 tf1 = float(true_f_alpha(sel.mask, self.truth_mask, self.config.alpha))
@@ -354,7 +352,6 @@ class ProgressiveQueryOperator:
                     true_f1=tf1,
                     plan_cost=float(plan.total_cost()),
                     plan_valid=n_valid,
-                    wall_time_s=wall,
                 )
             )
             if stop_when_exhausted and n_valid == 0:

@@ -64,6 +64,7 @@ import numpy as np
 
 from repro.core import ledger as ledger_lib
 from repro.core import state as state_lib
+from repro.core import tracing
 from repro.core.errors import CapacityError, SlotActiveError, SlotsExhaustedError
 from repro.core.executor import (
     EngineConfig,
@@ -374,38 +375,41 @@ class EngineSession:
         ledger/billing handle).
         """
         cols = self._query_columns(query)
-        if active is None:
-            active = jax.device_get(state.active)
-        active_np = np.asarray(active)
-        if slot is None:
-            free = np.flatnonzero(~active_np)
-            if free.size == 0:
-                raise SlotsExhaustedError(
-                    f"no free tenant slots (max_tenants={self.max_tenants}); "
-                    "retire a tenant or open the session with more slots",
-                    used=int(active_np.sum()),
-                    capacity=self.max_tenants,
-                    requested=1,
-                )
-            slot = int(free[0])
-        else:
-            if not 0 <= slot < self.max_tenants:
-                raise ValueError(f"slot {slot} out of range [0, {self.max_tenants})")
-            if active_np[slot]:
-                raise SlotActiveError(
-                    f"slot {slot} is already occupied; retire it first",
-                    slot=slot,
-                )
-        row = jnp.zeros((self.num_predicates,), bool).at[
-            jnp.asarray(cols, jnp.int32)
-        ].set(True)
-        state = dataclasses.replace(
-            state,
-            pred_mask=state.pred_mask.at[slot].set(row),
-            active=state.active.at[slot].set(True),
-            ledger=ledger_lib.reset_slot(state.ledger, slot),
-        )
-        return self.program.refresh(state), slot
+        with tracing.span(tracing.ADMIT) as span:
+            if active is None:
+                with tracing.span(tracing.SYNC):
+                    active = jax.device_get(state.active)
+            active_np = np.asarray(active)
+            if slot is None:
+                free = np.flatnonzero(~active_np)
+                if free.size == 0:
+                    raise SlotsExhaustedError(
+                        f"no free tenant slots (max_tenants={self.max_tenants}); "
+                        "retire a tenant or open the session with more slots",
+                        used=int(active_np.sum()),
+                        capacity=self.max_tenants,
+                        requested=1,
+                    )
+                slot = int(free[0])
+            else:
+                if not 0 <= slot < self.max_tenants:
+                    raise ValueError(f"slot {slot} out of range [0, {self.max_tenants})")
+                if active_np[slot]:
+                    raise SlotActiveError(
+                        f"slot {slot} is already occupied; retire it first",
+                        slot=slot,
+                    )
+            span.set_metadata(slot=slot)
+            row = jnp.zeros((self.num_predicates,), bool).at[
+                jnp.asarray(cols, jnp.int32)
+            ].set(True)
+            state = dataclasses.replace(
+                state,
+                pred_mask=state.pred_mask.at[slot].set(row),
+                active=state.active.at[slot].set(True),
+                ledger=ledger_lib.reset_slot(state.ledger, slot),
+            )
+            return self.program.refresh(state), slot
 
     def retire(
         self, state: SessionState, slot: int, *, active=None
@@ -421,21 +425,22 @@ class EngineSession:
         """
         if not 0 <= slot < self.max_tenants:
             raise ValueError(f"slot {slot} out of range [0, {self.max_tenants})")
-        occupied = (
-            bool(jax.device_get(state.active[slot]))
-            if active is None
-            else bool(np.asarray(active)[slot])
-        )
-        if not occupied:
-            raise ValueError(f"slot {slot} is not active")
-        state = dataclasses.replace(
-            state,
-            pred_mask=state.pred_mask.at[slot].set(
-                jnp.zeros((self.num_predicates,), bool)
-            ),
-            active=state.active.at[slot].set(False),
-        )
-        return self.program.refresh(state)
+        with tracing.span(tracing.RETIRE, slot=slot):
+            if active is None:
+                with tracing.span(tracing.SYNC):
+                    occupied = bool(jax.device_get(state.active[slot]))
+            else:
+                occupied = bool(np.asarray(active)[slot])
+            if not occupied:
+                raise ValueError(f"slot {slot} is not active")
+            state = dataclasses.replace(
+                state,
+                pred_mask=state.pred_mask.at[slot].set(
+                    jnp.zeros((self.num_predicates,), bool)
+                ),
+                active=state.active.at[slot].set(False),
+            )
+            return self.program.refresh(state)
 
     def refresh(self, state: SessionState) -> SessionState:
         """Recompute all derived state from the substrate + masks (jitted).
@@ -815,7 +820,6 @@ class SessionPipeline:
             t_done = time.perf_counter() - self._t0
             chunk_hist = prog.materialize_history(
                 [(length, host)],
-                wall_per_epoch=t_done / max(self.epochs_dispatched, 1),
                 collect_masks=collect,
                 stop_when_exhausted=False,
                 epoch_base=base,

@@ -50,6 +50,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import tracing
 from repro.core.plan import Plan
 from repro.models import transformer as tf
 from repro.models.config import ModelConfig
@@ -423,6 +424,7 @@ class ModelCascadeBank:
                 cfg = level["cfg"]
                 heads = arrays["heads"]
 
+                @tracing.scope(tracing.TRUNK)
                 def _backbone_probs(operands, cfg=cfg, heads=heads, trunk=arrays["trunk"]):
                     feats, s_prd = operands
                     # per-predicate input/output heads via vmap-shaped

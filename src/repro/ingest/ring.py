@@ -39,6 +39,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import tracing
 from repro.core.errors import CapacityError, IngestBackpressure
 
 _POLICIES = ("block", "shed", "spill")
@@ -184,29 +185,31 @@ class PendingRing:
                 got=str(batch.dtype),
                 where="PendingRing.push",
             )
-        if self.policy == "spill" and (self._count == self.num_slots or self._spilled):
-            # order preservation: once anything is spilled, EVERYTHING spills
-            # until the queue has drained back into slots
-            self._spilled.append(np.asarray(batch))
-            self.counters["spilled_batches"] += 1
-            self.counters["spilled_rows"] += int(batch.shape[0])
+        blocked = self._count == self.num_slots and self.policy == "block"
+        with tracing.span(tracing.PUSH, blocked=int(blocked)):
+            if self.policy == "spill" and (self._count == self.num_slots or self._spilled):
+                # order preservation: once anything is spilled, EVERYTHING
+                # spills until the queue has drained back into slots
+                self._spilled.append(np.asarray(batch))
+                self.counters["spilled_batches"] += 1
+                self.counters["spilled_rows"] += int(batch.shape[0])
+                return True
+            if self._count == self.num_slots:
+                if self.policy == "shed":
+                    self.counters["shed_batches"] += 1
+                    self.counters["shed_rows"] += int(batch.shape[0])
+                    return False
+                self.counters["blocked"] += 1
+                raise IngestBackpressure(
+                    f"pending-row ring is full ({self._count}/{self.num_slots} "
+                    f"slots); drain into the session and retry",
+                    occupied=self._count,
+                    capacity=self.num_slots,
+                    requested=int(batch.shape[0]),
+                    policy=self.policy,
+                )
+            self._enqueue(batch)
             return True
-        if self._count == self.num_slots:
-            if self.policy == "shed":
-                self.counters["shed_batches"] += 1
-                self.counters["shed_rows"] += int(batch.shape[0])
-                return False
-            self.counters["blocked"] += 1
-            raise IngestBackpressure(
-                f"pending-row ring is full ({self._count}/{self.num_slots} "
-                f"slots); drain into the session and retry",
-                occupied=self._count,
-                capacity=self.num_slots,
-                requested=int(batch.shape[0]),
-                policy=self.policy,
-            )
-        self._enqueue(batch)
-        return True
 
     # ---- consumer side -------------------------------------------------------
 
@@ -245,25 +248,29 @@ class PendingRing:
                 requested=total,
             )
         drained = 0
-        while self._count or self._spilled:
-            while self._count:
-                slot = self._head
-                m = self._fill[slot]
-                rows = self._buf[slot, :m]
-                state = session.ingest(
-                    state, rows, num_rows=num_rows, refresh=False
-                )
-                num_rows += m
-                drained += m
-                self._fill[slot] = 0
-                self._head = (self._head + 1) % self.num_slots
-                self._count -= 1
-                self.counters["drained_batches"] += 1
-                self.counters["drained_rows"] += m
-            # refill from the spill queue (preserving arrival order); the
-            # outer loop drains these freshly filled slots on its next pass
-            while self._spilled and self._count < self.num_slots:
-                self._enqueue(jnp.asarray(self._spilled.popleft()))
-        if drained:
-            state = session.program.refresh(state)
+        with tracing.span(
+            tracing.DRAIN, slots=self._count + len(self._spilled), rows=total
+        ):
+            while self._count or self._spilled:
+                while self._count:
+                    slot = self._head
+                    m = self._fill[slot]
+                    rows = self._buf[slot, :m]
+                    state = session.ingest(
+                        state, rows, num_rows=num_rows, refresh=False
+                    )
+                    num_rows += m
+                    drained += m
+                    self._fill[slot] = 0
+                    self._head = (self._head + 1) % self.num_slots
+                    self._count -= 1
+                    self.counters["drained_batches"] += 1
+                    self.counters["drained_rows"] += m
+                # refill from the spill queue (preserving arrival order); the
+                # outer loop drains these freshly filled slots on its next
+                # pass
+                while self._spilled and self._count < self.num_slots:
+                    self._enqueue(jnp.asarray(self._spilled.popleft()))
+            if drained:
+                state = session.program.refresh(state)
         return state, num_rows, drained

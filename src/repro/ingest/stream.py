@@ -31,6 +31,7 @@ from typing import Callable, Optional
 import jax
 import numpy as np
 
+from repro.core import tracing
 from repro.core.errors import IngestBackpressure
 from repro.ingest.ring import PendingRing
 
@@ -96,16 +97,20 @@ class IngestStream:
         flight — the double-buffer backstop, not the steady state."""
         i = self._next
         token = self._consumed[i]
+        gate = None
         if token is not None:
-            jax.block_until_ready(
-                self.ring._buf if token is _RING_WRITE else token
-            )
+            gate = self.ring._buf if token is _RING_WRITE else token
             self._consumed[i] = None
         m = rows.shape[0]
-        buf = self._staging[i]
-        np.copyto(buf[:m], rows, casting="unsafe")  # host-side quantization
-        self._next = 1 - i
-        return i, jax.device_put(buf[:m])
+        with tracing.span(
+            tracing.STAGE, rows=m, waited=int(gate is not None and not gate.is_ready())
+        ):
+            if gate is not None:
+                jax.block_until_ready(gate)
+            buf = self._staging[i]
+            np.copyto(buf[:m], rows, casting="unsafe")  # host-side quantization
+            self._next = 1 - i
+            return i, jax.device_put(buf[:m])
 
     def _throttle(self, m: int) -> None:
         if self.rate_rows_per_s is None:

@@ -1045,9 +1045,22 @@ def main(argv=None):
     ap.add_argument("--report", default=None,
                     help="write the session serve report as JSON (the CI "
                          "kill-and-resume job's bitwise diff surface)")
+    ap.add_argument("--profile-dir", default=None, metavar="DIR",
+                    help="record the serve loop with jax.profiler into DIR "
+                         "(README: Tracing a session)")
     args = ap.parse_args(argv)
 
     use_compile_cache()
+    if args.profile_dir is None:
+        return _serve(ap, args)
+    jax.profiler.start_trace(args.profile_dir)
+    try:
+        return _serve(ap, args)
+    finally:
+        jax.profiler.stop_trace()
+
+
+def _serve(ap, args) -> int:
     handler = PreemptionHandler().install()
     if args.session:
         if args.bank == "cascade":
