@@ -179,13 +179,15 @@ def _drive(session, state0, preds, pool_np, schedule, batch, slots, chunk,
 def _pallas_bf16_parity(seed: int = 0):
     """Re-check the dequant-in-tile exactness contract on a small fixture.
 
-    Planning-driving outputs (benefit / next_fn / cost) must be BITWISE
-    between the bf16-fed kernel and its f32-upcast reference in both
-    function-selection modes; best-mode ``est_joint`` is 1-ulp-stable (XLA
-    output-fusion contraction — see the kernel module docstring).
+    Planning-driving outputs (benefit / next_fn, and the plans they give,
+    costs included) must be BITWISE between the bf16-fed kernel and its
+    f32-upcast reference in both function-selection modes; best-mode
+    ``est_joint`` is 1-ulp-stable (XLA output-fusion contraction — see the
+    kernel module docstring).
     """
     from repro.core.decision_table import fallback_decision_table
     from repro.core.entropy import binary_entropy
+    from repro.core.plan import select_plan
     from repro.kernels.enrich_score import ops as es_ops
 
     p_, f_, n_, q_ = 3, 4, 512, 3
@@ -213,18 +215,21 @@ def _pallas_bf16_parity(seed: int = 0):
         bit = lambda a, b: bool(
             np.asarray(a).tobytes() == np.asarray(b).tobytes()
         )
+        plan_lo, plan_hi = (
+            jax.vmap(lambda b: select_plan(b, 64, costs))(x) for x in (lo, hi)
+        )
         ej_lo = np.asarray(lo.est_joint).view(np.int32).astype(np.int64)
         ej_hi = np.asarray(hi.est_joint).view(np.int32).astype(np.int64)
         out[mode] = dict(
             benefit_bitwise=bit(lo.benefit, hi.benefit),
             next_fn_bitwise=bit(lo.next_fn, hi.next_fn),
-            cost_bitwise=bit(lo.cost, hi.cost),
+            plan_bitwise=all(map(bit, plan_lo, plan_hi)),
             est_joint_max_ulp=int(np.abs(ej_lo - ej_hi).max()),
         )
     out["planning_outputs_bitwise"] = all(
         out[m][k]
         for m in ("table", "best")
-        for k in ("benefit_bitwise", "next_fn_bitwise", "cost_bitwise")
+        for k in ("benefit_bitwise", "next_fn_bitwise", "plan_bitwise")
     )
     return out
 

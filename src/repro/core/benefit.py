@@ -110,10 +110,26 @@ def restrict_benefits(
 
 
 class TripleBenefits(NamedTuple):
+    """Eq. 11 over every (object, predicate) lane.
+
+    A triple's cost is a function of its (predicate, function) alone, so
+    no per-lane cost is carried: the plan looks it up on the lanes it keeps
+    (``function_cost``, called by ``plan.select_plan``).
+    """
+
     benefit: jax.Array  # [N, P] f32; -inf where no candidate triple exists
     next_fn: jax.Array  # [N, P] int32; -1 where exhausted
     est_joint: jax.Array  # [N, P] f32; estimated joint prob if executed
-    cost: jax.Array  # [N, P] f32; cost of the selected function
+
+
+def function_cost(
+    costs: jax.Array,  # [P, F]
+    pred_idx: jax.Array,  # int32, any shape
+    next_fn: jax.Array,  # int32, pred_idx's shape; -1 where exhausted
+) -> jax.Array:
+    """Cost of each triple's function, floored at 1e-9 (Eq. 11 divides by
+    it); an exhausted lane (``next_fn`` -1) reads function 0's cost."""
+    return jnp.maximum(costs[pred_idx, jnp.maximum(next_fn, 0)], 1e-9)
 
 
 def estimate_pred_prob_after(
@@ -132,7 +148,6 @@ def compute_benefits(
     table: DecisionTable,
     costs: jax.Array,  # [P, F] per-(predicate, function) cost
     candidate_mask: jax.Array | None = None,  # [N] bool; default: ~in_answer (§4.1)
-    load_cost: jax.Array | None = None,  # [N] optional per-object load cost (Eq. 12)
     function_selection: str = "table",  # "table" (paper §4.2) | "best" (beyond-paper)
 ) -> TripleBenefits:
     """Vectorized Eq. 11 over all candidate (object, predicate) pairs.
@@ -152,8 +167,6 @@ def compute_benefits(
             state.pred_prob[..., None], jnp.where(jnp.isfinite(dh_all), dh_all, 0.0)
         )
         cost = jnp.maximum(jnp.broadcast_to(costs[None], dh_all.shape), 1e-9)
-        if load_cost is not None:
-            cost = cost + load_cost[:, None, None]
         if query.is_conjunctive:
             est_joint_all = query.conjunctive_update(
                 state.joint_prob[:, None, None], state.pred_prob[..., None], p_hat_all
@@ -180,16 +193,13 @@ def compute_benefits(
         nf = jnp.argmax(ben_all, axis=-1).astype(jnp.int32)  # [N, P]
         benefit = jnp.max(ben_all, axis=-1)
         est_joint = jnp.take_along_axis(est_joint_all, nf[..., None], axis=-1)[..., 0]
-        cost = jnp.take_along_axis(cost, nf[..., None], axis=-1)[..., 0]
         nf = jnp.where(jnp.isfinite(benefit), nf, -1)
         valid = nf >= 0
         if candidate_mask is None:
             candidate_mask = ~state.in_answer
         valid = valid & candidate_mask[:, None]
         benefit = jnp.where(valid, benefit, NEG_INF)
-        return TripleBenefits(
-            benefit=benefit, next_fn=nf, est_joint=est_joint, cost=cost
-        )
+        return TripleBenefits(benefit=benefit, next_fn=nf, est_joint=est_joint)
 
     nf, dh = table.lookup(pred_idx, state_id, state.uncertainty)  # [N, P] each
 
@@ -207,12 +217,7 @@ def compute_benefits(
 
     est_joint = jnp.clip(est_joint, 0.0, 1.0)
 
-    fn_safe = jnp.maximum(nf, 0)
-    cost = costs[pred_idx, fn_safe]  # [N, P]
-    if load_cost is not None:
-        cost = cost + load_cost[:, None]  # Eq. 12: c_load + c_fn
-    cost = jnp.maximum(cost, 1e-9)
-
+    cost = function_cost(costs, pred_idx, nf)  # [N, P]
     benefit = state.joint_prob[:, None] * est_joint / cost  # Eq. 11
 
     valid = nf >= 0
@@ -220,7 +225,7 @@ def compute_benefits(
         candidate_mask = ~state.in_answer  # §4.1 Candidate = O - Answer_{i-1}
     valid = valid & candidate_mask[:, None]
     benefit = jnp.where(valid, benefit, NEG_INF)
-    return TripleBenefits(benefit=benefit, next_fn=nf, est_joint=est_joint, cost=cost)
+    return TripleBenefits(benefit=benefit, next_fn=nf, est_joint=est_joint)
 
 
 def compute_benefits_batched(
@@ -265,10 +270,9 @@ def compute_benefits_batched(
         # ties resolve exactly as ``argmax`` would.
         benefit = jnp.full((q, n, p), NEG_INF, jnp.float32)
         nf = jnp.full((q, n, p), -1, jnp.int32)
-        # lanes with no finite benefit keep est 0 and function 0's cost, as
-        # the fused kernel reports them
+        # lanes with no finite benefit keep est 0, as the fused kernel
+        # reports them
         est_joint = jnp.zeros((q, n, p), jnp.float32)
-        cost_q = jnp.broadcast_to(jnp.maximum(costs[:, 0], 1e-9), (q, n, p))
         for fi in range(dh_all.shape[-1]):
             dh = dh_all[..., fi]
             h_hat = jnp.clip(h + jnp.where(jnp.isfinite(dh), dh, 0.0), 0.0, 1.0)
@@ -287,10 +291,7 @@ def compute_benefits_batched(
             benefit = jnp.where(better, ben, benefit)
             nf = jnp.where(better, fi, nf)
             est_joint = jnp.where(better, est, est_joint)
-            cost_q = jnp.where(better, cost, cost_q)
-        return TripleBenefits(
-            benefit=benefit, next_fn=nf, est_joint=est_joint, cost=cost_q
-        )
+        return TripleBenefits(benefit=benefit, next_fn=nf, est_joint=est_joint)
 
     nf, dh = table.lookup(pred_idx, state_id, uncertainty)  # [N, P] each
     _, p_hat = estimate_pred_prob_after(pred_prob, dh)
@@ -301,13 +302,12 @@ def compute_benefits_batched(
         0.0,
         1.0,
     )  # [Q, N, P]
-    cost = jnp.maximum(costs[pred_idx, jnp.maximum(nf, 0)], 1e-9)  # [N, P]
+    cost = function_cost(costs, pred_idx, nf)  # [N, P], Eq. 11's divisor
     benefit = joint_prob[:, :, None] * est_joint / cost[None]
     return TripleBenefits(
         benefit=benefit,
         next_fn=jnp.broadcast_to(nf[None], (q, n, p)),
         est_joint=est_joint,
-        cost=jnp.broadcast_to(cost[None], (q, n, p)),
     )
 
 
@@ -338,8 +338,9 @@ def benefit_exact_slow(
     ef = jax.vmap(
         lambda o: jax.vmap(lambda c: ef_with(o, c))(jnp.arange(p))
     )(obj_grid)  # [N, P]
-    benefit = (ef - base.expected_f) / fast.cost
+    pred_idx = jnp.broadcast_to(jnp.arange(p, dtype=jnp.int32)[None, :], (n, p))
+    benefit = (ef - base.expected_f) / function_cost(costs, pred_idx, fast.next_fn)
     benefit = jnp.where(jnp.isfinite(fast.benefit), benefit, NEG_INF)
     return TripleBenefits(
-        benefit=benefit, next_fn=fast.next_fn, est_joint=fast.est_joint, cost=fast.cost
+        benefit=benefit, next_fn=fast.next_fn, est_joint=fast.est_joint
     )

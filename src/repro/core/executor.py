@@ -149,6 +149,7 @@ def select_plans_batched(
     plan_size: int,
     num_shards: int,
     num_predicates: int,
+    costs: jax.Array,  # [P, F]
 ) -> plan_lib.Plan:
     """Per-query plan selection, optionally sharded over the object axis.
 
@@ -158,7 +159,7 @@ def select_plans_batched(
     then the survivors reduce through the EXACT cross-shard merge, so the
     result is byte-identical to the unsharded top-k on every valid lane.
     """
-    sel = functools.partial(plan_lib.select_plan, plan_size=plan_size)
+    sel = functools.partial(plan_lib.select_plan, plan_size=plan_size, costs=costs)
     if num_shards <= 1:
         return jax.vmap(sel)(benefits)
     s = num_shards
@@ -465,7 +466,7 @@ class EpochProgram:
                     self.table, self.costs,
                     function_selection=mode,
                 )
-            benefit, nf, est_joint, cost = tb
+            benefit, nf, est_joint = tb
             valid = (
                 (nf >= 0)
                 & state.pred_mask[:, None, :]
@@ -484,7 +485,7 @@ class EpochProgram:
             benefit = jax.vmap(
                 lambda b, c: benefit_lib.restrict_benefits(b, c, cfg.plan_size)
             )(benefit, cand)
-        return TripleBenefits(benefit=benefit, next_fn=nf, est_joint=est_joint, cost=cost)
+        return TripleBenefits(benefit=benefit, next_fn=nf, est_joint=est_joint)
 
     def _plan_part(self, state: SessionState):
         """The superstep up to the bank boundary: score, select, dedup-merge."""
@@ -497,6 +498,7 @@ class EpochProgram:
                 plan_size=cfg.plan_size,
                 num_shards=cfg.num_shards,
                 num_predicates=self.num_predicates,
+                costs=self.costs,
             )
         with tracing.scope(tracing.MERGE):
             merged, want_bits = plan_lib.merge_plans_dedup_wants(

@@ -560,7 +560,7 @@ class MultiQueryEngine:
                     per.joint_prob, self.table, self.costs,
                     function_selection=mode,
                 )
-            benefit, nf, est_joint, cost = tb
+            benefit, nf, est_joint = tb
         else:
             # General ASTs: per-query column-substitution re-evaluation.
             pred_idx = jnp.broadcast_to(
@@ -583,8 +583,7 @@ class MultiQueryEngine:
                 ]
             )
             est_joint = jnp.clip(est_joint, 0.0, 1.0)
-            fn_safe = jnp.maximum(nf, 0)
-            cost = jnp.maximum(self.costs[pred_idx, fn_safe], 1e-9)  # [Q, N, P]
+            cost = benefit_lib.function_cost(self.costs, pred_idx, nf)  # [Q, N, P]
             benefit = per.joint_prob[..., None] * est_joint / cost  # Eq. 11
 
         valid = (nf >= 0) & pred_mask[:, None, :]
@@ -611,7 +610,7 @@ class MultiQueryEngine:
         benefit = jax.vmap(
             lambda b, c: restrict_benefits(b, c, cfg.plan_size)
         )(benefit, cand)
-        return TripleBenefits(benefit=benefit, next_fn=nf, est_joint=est_joint, cost=cost)
+        return TripleBenefits(benefit=benefit, next_fn=nf, est_joint=est_joint)
 
     def _plan_epoch(self, state: MultiQueryState) -> tuple[plan_lib.Plan, plan_lib.Plan]:
         """-> (per-query plans [Q, K], merged deduplicated plan [M])."""
@@ -622,6 +621,7 @@ class MultiQueryEngine:
             plan_size=cfg.plan_size,
             num_shards=cfg.num_shards,
             num_predicates=self.query_set.num_predicates,
+            costs=self.costs,
         )
         merged = plan_lib.merge_plans_dedup(
             plans,

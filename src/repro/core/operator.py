@@ -248,7 +248,9 @@ class ProgressiveQueryOperator:
         benefits = benefits._replace(
             benefit=restrict_benefits(benefits.benefit, cand, cfg.plan_size)
         )
-        return plan_lib.select_plan(benefits, cfg.plan_size, cfg.epoch_cost_budget)
+        return plan_lib.select_plan(
+            benefits, cfg.plan_size, self.costs, cfg.epoch_cost_budget
+        )
 
     def _apply_and_select(
         self,

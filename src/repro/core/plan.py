@@ -24,7 +24,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.benefit import TripleBenefits
+from repro.core.benefit import TripleBenefits, function_cost
 
 
 class Plan(NamedTuple):
@@ -105,6 +105,7 @@ def gather_object_idx(plan: Plan, num_objects: int) -> jax.Array:
 def select_plan(
     benefits: TripleBenefits,
     plan_size: int,
+    costs: jax.Array,  # [P, F] per-(predicate, function) cost
     cost_budget: float | jax.Array | None = None,
 ) -> Plan:
     """Top-``plan_size`` triples by benefit, optionally cost-budget-masked.
@@ -114,6 +115,10 @@ def select_plan(
     Triples_i of §4.2.  Ordering contract: descending benefit, ties broken by
     ascending flat (object * P + predicate) index — ``merge_sharded_plans_exact``
     reproduces it across shards.
+
+    Each kept lane's cost is looked up from ``costs`` by its (predicate,
+    function), floored at 1e-9 (``benefit.function_cost``), on the K lanes
+    only: the same floats the scorers divide Eq. 11 by.
     """
     n, p = benefits.benefit.shape
     flat = benefits.benefit.reshape(-1)
@@ -122,7 +127,7 @@ def select_plan(
     obj = (top_idx // p).astype(jnp.int32)
     prd = (top_idx % p).astype(jnp.int32)
     fn = benefits.next_fn.reshape(-1)[top_idx]
-    cost = benefits.cost.reshape(-1)[top_idx]
+    cost = function_cost(costs, prd, fn)
     valid = jnp.isfinite(top_vals) & (fn >= 0)
     if cost_budget is not None:
         # Triples are executed in benefit order until the budget is consumed

@@ -5,7 +5,9 @@
 (``OperatorConfig.use_fused_kernel``); ``fused_benefits_batched`` is the
 multi-query analogue of ``repro.core.benefit.compute_benefits_batched``
 (``MultiQueryConfig.backend="pallas"``), including the fused ``"best"``-mode
-argmax that never materializes [Q, N, P, F] in HBM."""
+argmax that never materializes [Q, N, P, F] in HBM.  Neither returns a
+per-lane cost: the kernel prices Eq. 11 in-tile, and the plan looks up the
+cost of the lanes it keeps (``repro.core.benefit.function_cost``)."""
 
 from __future__ import annotations
 
@@ -119,10 +121,8 @@ def fused_benefits(
     )
     benefit, next_fn, est_joint = (unflat(x) for x in out)
     benefit = jnp.where(benefit <= -1e29, -jnp.inf, benefit)
-    nf = next_fn.astype(jnp.int32)
-    cost = costs[pred_idx, jnp.maximum(nf, 0)]
     return TripleBenefits(
-        benefit=benefit, next_fn=nf, est_joint=est_joint, cost=cost
+        benefit=benefit, next_fn=next_fn.astype(jnp.int32), est_joint=est_joint
     )
 
 
@@ -209,8 +209,6 @@ def fused_benefits_batched(
 
     benefit, next_fn, est_joint = (unflat(x) for x in out)
     benefit = jnp.where(benefit <= -1e29, -jnp.inf, benefit)
-    nf = next_fn.astype(jnp.int32)
-    cost = jnp.maximum(costs[pred_idx[None], jnp.maximum(nf, 0)], 1e-9)
     return TripleBenefits(
-        benefit=benefit, next_fn=nf, est_joint=est_joint, cost=cost
+        benefit=benefit, next_fn=next_fn.astype(jnp.int32), est_joint=est_joint
     )

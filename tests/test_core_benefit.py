@@ -101,13 +101,17 @@ def test_plan_selection_order_and_budget():
     costs = jnp.asarray(np.tile([0.02, 0.1, 0.4, 0.9], (2, 1)), jnp.float32)
     out = compute_benefits(stt, query, table, costs,
                            candidate_mask=jnp.ones((stt.num_objects,), bool))
-    plan = select_plan(out, plan_size=16, cost_budget=1.0)
+    plan = select_plan(out, plan_size=16, costs=costs, cost_budget=1.0)
     b = np.asarray(plan.benefit)
     assert np.all(np.diff(b) <= 1e-6)  # descending
     assert float(plan.total_cost()) <= 1.0 + 1e-5
-    # valid triples point at real objects/functions
+    # valid triples point at real objects/functions, billed their cost
     v = np.asarray(plan.valid)
     assert np.all(np.asarray(plan.func_idx)[v] >= 0)
+    np.testing.assert_array_equal(
+        np.asarray(plan.cost)[v],
+        np.asarray(costs)[np.asarray(plan.pred_idx), np.asarray(plan.func_idx)][v],
+    )
 
 
 def test_eq11_preserves_exact_benefit_order_lemma4():
@@ -167,11 +171,17 @@ def test_batched_reference_recomputes_entropy_like_single_query(mode):
     valid = np.asarray(one.next_fn) >= 0
     assert valid.any()
     np.testing.assert_array_equal(np.asarray(batched.next_fn[0]), np.asarray(one.next_fn))
-    for name in ("benefit", "est_joint", "cost"):
+    for name in ("benefit", "est_joint"):
         np.testing.assert_array_equal(
             np.asarray(getattr(batched, name)[0])[valid],
             np.asarray(getattr(one, name))[valid],
         )
+    # and the plans they give bill the same costs
+    masked = lambda tb: tb._replace(benefit=jnp.where(valid, tb.benefit, -jnp.inf))
+    plan_b = select_plan(masked(jax.tree.map(lambda x: x[0], batched)), 32, costs)
+    plan_1 = select_plan(masked(one), 32, costs)
+    for a, b in zip(plan_b, plan_1):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_batched_best_mode_f32_unchanged_by_running_argmax():
@@ -211,7 +221,11 @@ def test_batched_best_mode_f32_unchanged_by_running_argmax():
     pick = lambda x: np.take_along_axis(np.asarray(x), nf[..., None], -1)[..., 0]
     np.testing.assert_array_equal(np.asarray(out.benefit)[valid], pick(ben)[valid])
     np.testing.assert_array_equal(np.asarray(out.est_joint)[valid], pick(est)[valid])
-    np.testing.assert_array_equal(
-        np.asarray(out.cost)[valid],
-        pick(jnp.broadcast_to(cost[None], ben.shape))[valid],
-    )
+    # the plan bills each kept lane its argmax function's floored cost
+    plans = jax.vmap(lambda b: select_plan(b, 32, costs))(out)
+    pv = np.asarray(plans.valid)
+    obj, prd = np.asarray(plans.object_idx), np.asarray(plans.pred_idx)
+    lane = (np.arange(q)[:, None], obj, prd)
+    np.testing.assert_array_equal(np.asarray(plans.func_idx)[pv], nf[lane][pv])
+    dense_cost = pick(jnp.broadcast_to(cost[None], ben.shape))
+    np.testing.assert_array_equal(np.asarray(plans.cost)[pv], dense_cost[lane][pv])

@@ -144,7 +144,7 @@ def test_blocks_load_cost_and_swap():
     ben[70:80] = 10.0
     tb = TripleBenefits(
         benefit=jnp.asarray(ben), next_fn=jnp.zeros((100, 1), jnp.int32),
-        est_joint=jnp.zeros((100, 1)), cost=jnp.ones((100, 1)),
+        est_joint=jnp.zeros((100, 1)),
     )
     bb = block_benefits(bs, tb)
     assert int(jnp.argmax(bb)) == 7

@@ -26,7 +26,6 @@ def _mk_benefits(seed, n, p):
         benefit=jnp.asarray(b),
         next_fn=jnp.zeros((n, p), jnp.int32),
         est_joint=jnp.asarray(rng.uniform(size=(n, p)).astype(np.float32)),
-        cost=jnp.full((n, p), 0.1, jnp.float32),
     )
 
 
@@ -34,7 +33,8 @@ def test_hierarchical_topk_equals_global_topk():
     """Per-shard top-k -> merge == global top-k (exactness of the hierarchy)."""
     n, p, shards, k = 256, 2, 4, 16
     ben = _mk_benefits(0, n, p)
-    global_plan = select_plan(ben, plan_size=k)
+    costs = jnp.full((p, 1), 0.1, jnp.float32)
+    global_plan = select_plan(ben, plan_size=k, costs=costs)
 
     per = n // shards
     local_plans = []
@@ -43,9 +43,8 @@ def test_hierarchical_topk_equals_global_topk():
             benefit=ben.benefit[s * per:(s + 1) * per],
             next_fn=ben.next_fn[s * per:(s + 1) * per],
             est_joint=ben.est_joint[s * per:(s + 1) * per],
-            cost=ben.cost[s * per:(s + 1) * per],
         )
-        lp = select_plan(local, plan_size=k)
+        lp = select_plan(local, plan_size=k, costs=costs)
         # re-index objects to global ids
         lp = lp._replace(object_idx=lp.object_idx + s * per)
         local_plans.append(lp)
@@ -60,6 +59,8 @@ def test_hierarchical_topk_equals_global_topk():
     assert set(np.asarray(merged.object_idx).tolist()) == set(
         np.asarray(global_plan.object_idx).tolist()
     )
+    # every kept lane is billed its (predicate, function)'s cost
+    np.testing.assert_array_equal(np.asarray(merged.cost), np.float32(0.1))
 
 
 @given(st.integers(0, 500))

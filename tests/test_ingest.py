@@ -3,6 +3,8 @@ parameterized substrate: ring semantics under every backpressure policy,
 bitwise ring-vs-direct parity, staged transfers, bf16 sessions end to end,
 checkpoint dtype strictness, and the dequant-in-tile exactness contract."""
 
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -516,9 +518,10 @@ def _parity_fixture(seed=0, n=512, q=3, p=3, f=4):
 @pytest.mark.parametrize("mode", ["table", "best"])
 def test_pallas_bf16_dequant_in_tile_parity(mode):
     """The exactness contract: bf16-fed kernels match the f32-upcast
-    reference BITWISE on every planning-driving output (benefit / next_fn /
-    cost); table-mode est_joint is bitwise too, best-mode est_joint is
-    1-ulp-stable (XLA output-fusion contraction — kernel docstring)."""
+    reference BITWISE on every planning-driving output (benefit / next_fn,
+    and the plans they give, costs included); table-mode est_joint is
+    bitwise too, best-mode est_joint is 1-ulp-stable (XLA output-fusion
+    contraction — kernel docstring)."""
     from repro.kernels.enrich_score import ops as es_ops
 
     table, costs, pp, unc, sid, joint = _parity_fixture()
@@ -531,13 +534,32 @@ def test_pallas_bf16_dequant_in_tile_parity(mode):
         joint.astype(jnp.float32), table, costs,
         function_selection=mode, interpret=True,
     )
-    for name in ("benefit", "next_fn", "cost"):
+    for name in ("benefit", "next_fn"):
         a, b = np.asarray(getattr(lo, name)), np.asarray(getattr(hi, name))
         assert a.tobytes() == b.tobytes(), f"{mode}.{name} not bitwise"
+    # the plans they give, costs included
+    from repro.core.plan import select_plan
+
+    plans = [jax.vmap(lambda b: select_plan(b, 64, costs))(x) for x in (lo, hi)]
+    for name, a, b in zip(plans[0]._fields, *plans):
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes(), f"{mode}.plan.{name}"
     ej_lo = np.asarray(lo.est_joint).view(np.int32).astype(np.int64)
     ej_hi = np.asarray(hi.est_joint).view(np.int32).astype(np.int64)
     max_ulp = int(np.abs(ej_lo - ej_hi).max())
     assert max_ulp <= (0 if mode == "table" else 1)
+
+
+def test_ingest_benchmark_parity_block_holds(monkeypatch):
+    """``benchmarks/ingest.py`` reports the same contract in its JSON (CI
+    asserts ``planning_outputs_bitwise``); it must run on the scorers'
+    current outputs."""
+    monkeypatch.syspath_prepend(str(Path(__file__).resolve().parents[1]))
+    from benchmarks.ingest import _pallas_bf16_parity
+
+    out = _pallas_bf16_parity()
+    assert out["planning_outputs_bitwise"] is True
+    assert out["table"]["est_joint_max_ulp"] == 0
+    assert out["best"]["est_joint_max_ulp"] <= 1
 
 
 def test_pallas_mixed_probability_dtypes_raise():

@@ -19,6 +19,7 @@ from repro.core import Predicate, conjunction
 from repro.core.benefit import compute_benefits
 from repro.core.combine import default_combine_params
 from repro.core.decision_table import fallback_decision_table, learn_decision_table
+from repro.core.plan import select_plan
 from repro.core.state import init_state, refresh_derived
 
 
@@ -304,9 +305,14 @@ def _assert_batched_parity(stt, joint, table, costs, mode):
         np.asarray(out.est_joint)[fin], np.asarray(ref.est_joint)[fin],
         rtol=5e-3, atol=5e-3,
     )
-    np.testing.assert_allclose(
-        np.asarray(out.cost)[fin], np.asarray(ref.cost)[fin], rtol=1e-6
-    )
+    # the plan bills each kept lane the cost the reference priced it at
+    plans = jax.vmap(lambda b: select_plan(b, 16, costs))(out)
+    pv = np.asarray(plans.valid)
+    obj, prd = np.asarray(plans.object_idx), np.asarray(plans.pred_idx)
+    ref_fn = np.asarray(ref.next_fn)[np.arange(len(obj))[:, None], obj, prd]
+    np.testing.assert_array_equal(np.asarray(plans.func_idx)[pv], ref_fn[pv])
+    ref_cost = np.maximum(np.asarray(costs)[prd, np.maximum(ref_fn, 0)], 1e-9)
+    np.testing.assert_array_equal(np.asarray(plans.cost)[pv], ref_cost[pv])
     return fin
 
 
@@ -430,10 +436,15 @@ def test_enrich_score_batched_bitwise_with_reference_under_jit(mode, dtype):
     valid = np.asarray(ref.next_fn) >= 0
     assert valid.any() and not valid.all()
     np.testing.assert_array_equal(np.asarray(out.next_fn), np.asarray(ref.next_fn))
-    for name in ("benefit", "est_joint", "cost"):
+    for name in ("benefit", "est_joint"):
         np.testing.assert_array_equal(
             np.asarray(getattr(out, name))[valid], np.asarray(getattr(ref, name))[valid]
         )
+    # the plans they give, costs included, as the superstep selects them
+    masked = lambda tb: tb._replace(benefit=jnp.where(valid, tb.benefit, -jnp.inf))
+    plan = jax.jit(jax.vmap(lambda b: select_plan(b, 128, costs)))
+    for a, b in zip(plan(masked(out)), plan(masked(ref))):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_enrich_score_batched_with_learned_table():

@@ -165,6 +165,7 @@ def test_ingested_rows_become_candidates_and_invalid_rows_never_plan():
         plans = select_plans_batched(
             benefits, plan_size=sess.config.plan_size,
             num_shards=1, num_predicates=sess.num_predicates,
+            costs=sess.costs,
         )
         v = np.asarray(plans.valid)
         return np.asarray(plans.object_idx)[v]
@@ -382,7 +383,7 @@ def test_padded_plan_lanes_inert_at_num_rows_equals_capacity():
     benefits = sess.program._benefits(st, st.row_valid())
     plans = select_plans_batched(
         benefits, plan_size=sess.config.plan_size, num_shards=1,
-        num_predicates=sess.num_predicates,
+        num_predicates=sess.num_predicates, costs=sess.costs,
     )
     merged, want_bits = merge_plans_dedup_wants(
         plans, sess.num_predicates, sess.num_functions,

@@ -1,6 +1,10 @@
 """Fused epoch superstep + sharded planning: scan-vs-loop driver parity,
 byte-identical sharded plan selection, hierarchical dedup exactness, triple-key
-overflow guards, and baseline plan rank scores."""
+overflow guards, baseline plan rank scores, and plan costs looked up on the
+kept lanes only."""
+
+import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -8,6 +12,7 @@ import numpy as np
 import pytest
 
 from repro.core import (
+    EngineSession,
     MultiQueryConfig,
     MultiQueryEngine,
     OperatorConfig,
@@ -302,14 +307,19 @@ def test_merge_sharded_plans_exact_matches_select_plan():
             jnp.int32,
         ),
         est_joint=jnp.asarray(rng.uniform(size=(n, p)).astype(np.float32)),
-        cost=jnp.asarray(rng.uniform(0.1, 1, size=(n, p)).astype(np.float32)),
     )
-    global_plan = select_plan(tb, plan_size=k)
+    costs = jnp.asarray(rng.uniform(0.1, 1, size=(p, 4)).astype(np.float32))
+    global_plan = select_plan(tb, plan_size=k, costs=costs)
+    v = np.asarray(global_plan.valid)
+    np.testing.assert_array_equal(
+        np.asarray(global_plan.cost)[v],
+        np.asarray(costs)[np.asarray(global_plan.pred_idx), np.asarray(global_plan.func_idx)][v],
+    )
     per = n // shards
     locals_ = []
     for s in range(shards):
         sl = TripleBenefits(*(x[s * per:(s + 1) * per] for x in tb))
-        lp = select_plan(sl, plan_size=k)
+        lp = select_plan(sl, plan_size=k, costs=costs)
         locals_.append(lp._replace(object_idx=lp.object_idx + s * per))
     stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *locals_)
     merged = merge_sharded_plans_exact(stacked, plan_size=k, num_predicates=p)
@@ -356,3 +366,153 @@ def test_static_plan_benefit_is_descending_rank():
     merged = merge_plans_dedup(dup, num_predicates=1, num_functions=1,
                                num_objects=m)
     assert int(merged.num_valid()) == plan_size
+
+
+# ------------------------------------------ plan costs looked up after top-k --
+
+
+def _old_rule_select_plans_batched(benefits, plan_size, num_shards,
+                                   num_predicates, costs):
+    """Plan selection as it was when the scorers priced every lane: the
+    [Q, N, P] cost ``max(costs[p, max(nf, 0)], 1e-9)`` built over all lanes,
+    resharded beside the benefits and indexed at the top-k lanes."""
+    q, n, p = benefits.benefit.shape
+    pred_idx = jnp.arange(p, dtype=jnp.int32)[None, None, :]
+    cost = jnp.maximum(costs[pred_idx, jnp.maximum(benefits.next_fn, 0)], 1e-9)
+    leaves = (benefits.benefit, benefits.next_fn, cost)
+
+    def sel(benefit, next_fn, cost):
+        top_vals, top_idx = jax.lax.top_k(benefit.reshape(-1), plan_size)
+        fn = next_fn.reshape(-1)[top_idx]
+        return Plan(
+            object_idx=(top_idx // p).astype(jnp.int32),
+            pred_idx=(top_idx % p).astype(jnp.int32),
+            func_idx=fn.astype(jnp.int32),
+            benefit=top_vals,
+            cost=cost.reshape(-1)[top_idx],
+            valid=jnp.isfinite(top_vals) & (fn >= 0),
+        )
+
+    if num_shards <= 1:
+        return jax.vmap(sel)(*leaves)
+    s, per = num_shards, n // num_shards
+    local = [x.reshape(q, s, per, p).transpose(1, 0, 2, 3) for x in leaves]
+    plans = jax.vmap(jax.vmap(sel))(*local)
+    plans = plans._replace(
+        object_idx=plans.object_idx
+        + (jnp.arange(s, dtype=jnp.int32) * per)[:, None, None]
+    )
+    return jax.vmap(functools.partial(
+        merge_sharded_plans_exact, plan_size=plan_size,
+        num_predicates=num_predicates,
+    ))(jax.tree.map(lambda x: x.transpose(1, 0, 2), plans))
+
+
+@pytest.mark.parametrize("budget", [None, 0.5])
+@pytest.mark.parametrize("num_shards", [1, 2])
+@pytest.mark.parametrize("function_selection", ["table", "best"])
+@pytest.mark.parametrize("backend", ["jnp", "pallas"])
+def test_plan_cost_lookup_matches_old_rule(
+    monkeypatch, backend, function_selection, num_shards, budget
+):
+    """Plans that look their costs up on the K kept lanes are bitwise the
+    plans indexed out of the scorers' old all-lane cost, and so are the
+    superstep's spend, requested cost and ledger: with an exhausted
+    predicate (every function quarantined, so nf = -1), a quarantined
+    function, a cost under the 1e-9 floor and an inactive slot."""
+    from repro.core import executor as executor_lib
+
+    preds, corpus, _, combine, table = _world()
+    costs = np.array(corpus.costs)
+    costs[1, 3] = 1e-12  # floored to 1e-9 by both rules
+    cfg = MultiQueryConfig(
+        plan_size=16, backend=backend, pallas_interpret=True,
+        function_selection=function_selection, num_shards=num_shards,
+        epoch_cost_budget=budget,
+    )
+
+    def run():
+        sess = EngineSession(
+            [p.positive() for p in preds], table, combine, costs,
+            capacity=N, max_tenants=4, config=cfg,
+        )
+        st = sess.init_state(corpus.func_probs)
+        for q in (conjunction(preds[0], preds[1]),
+                  conjunction(preds[1], preds[2], preds[3])):
+            st, _ = sess.admit(st, q)
+        st = sess.quarantine(st, 0, 1)
+        for f in range(F):
+            st = sess.quarantine(st, 3, f)
+        prog = sess.program
+
+        @jax.jit
+        def superstep(st):  # EpochProgram._superstep, keeping its plans
+            plans, merged, want_bits = prog._plan_part(st)
+            outputs = prog._gather_outputs(st, merged, None)
+            st, stats = prog._apply_part(st, plans, merged, want_bits, outputs)
+            return st, (plans, merged, want_bits, stats["requested_cost"],
+                        stats["cost_spent"], st.ledger)
+
+        epochs = []
+        for _ in range(3):
+            st, out = superstep(st)
+            epochs.append(out)
+        return epochs
+
+    with monkeypatch.context() as m:
+        m.setattr(executor_lib, "select_plans_batched",
+                  _old_rule_select_plans_batched)
+        old = run()
+    new = run()
+
+    plans = new[0][0]
+    fn, prd = np.asarray(plans.func_idx), np.asarray(plans.pred_idx)
+    v = np.asarray(plans.valid)
+    assert (fn == -1).any(), "an exhausted lane among the kept lanes"
+    assert (v & (prd == 1) & (fn == 3)).any(), "a planned sub-floor cost"
+    assert not (v & (prd == 0) & (fn == 1)).any(), "quarantine planned"
+    for o, n in zip(old, new, strict=True):
+        leaves_o, leaves_n = jax.tree.leaves(o), jax.tree.leaves(n)
+        assert len(leaves_o) == len(leaves_n)
+        for a, b in zip(leaves_o, leaves_n):
+            assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+
+
+def _instruction_shapes(hlo: str) -> dict:
+    """Instruction name -> (dtype, element count) over a module's text."""
+    out = {}
+    for name, dtype, dims in re.findall(
+        r"%([\w.\-]+) = (\w+)\[([\d,]*)\]", hlo
+    ):
+        out[name] = (dtype, int(np.prod([int(d) for d in dims.split(",") if d])))
+    return out
+
+
+@pytest.mark.parametrize("function_selection", ["table", "best"])
+def test_superstep_gathers_no_per_lane_cost(function_selection):
+    """The compiled superstep gathers nothing S*C*P-sized out of the [P, F]
+    cost table: a plan looks its K lanes' costs up after top-k."""
+    s_, c_, p_, f_ = 4, 4096, 4, 4
+    preds = [Predicate(i, 1) for i in range(p_)]
+    corpus = make_corpus(jax.random.PRNGKey(0), c_, [p.tag_type for p in preds],
+                         [p.tag for p in preds])
+    sess = EngineSession(
+        [p.positive() for p in preds], fallback_decision_table(p_, f_, corpus.aucs),
+        default_combine_params(corpus.aucs), corpus.costs,
+        capacity=c_, max_tenants=s_,
+        config=MultiQueryConfig(plan_size=32, backend="pallas",
+                                pallas_interpret=True,
+                                function_selection=function_selection),
+    )
+    st = sess.init_state(corpus.func_probs)
+    st, _ = sess.admit(st, conjunction(preds[0], preds[1]))
+    sess.run(st, 1)
+    hlo = dict(sess.program.compiled_hlo())["superstep"]
+    shapes = _instruction_shapes(hlo)
+    gathers = re.findall(r"%([\w.\-]+) = \w+\[[\d,]*\]\S* gather\(%([\w.\-]+)", hlo)
+    assert gathers, "the superstep's gathers were not found"
+    per_lane = [
+        g for g, operand in gathers
+        if shapes[operand] == ("f32", p_ * f_) and shapes[g][1] == s_ * c_ * p_
+    ]
+    assert not per_lane, f"per-lane cost gathers: {per_lane}"
