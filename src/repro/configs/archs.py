@@ -240,6 +240,43 @@ def mamba2_370m_smoke() -> ModelConfig:
     )
 
 
+# ------------------------------------------------------------------ lfm2 ---
+
+_LFM2_LAYER_TYPES = ("conv", "conv") + ("global", "conv", "conv", "conv") * 9 + ("global", "conv")
+
+
+def lfm2_24b_a2b() -> ModelConfig:
+    """[hf:LiquidAI/LFM2-24B-A2B config.json, model_type lfm2_moe] 40L d2048
+    32H kv8 (head_dim 64) v65536 — 30 gated short-conv layers (L_cache 3)
+    and 10 QK-normed GQA attention layers (conv, conv, then (attention,
+    conv, conv, conv) repeated); 2 leading dense SwiGLU layers (ff 11776),
+    then 38 MoE layers: 64 experts (ff 1536) top-4, sigmoid router with a
+    selection-only expert bias, normalised top-k weights, routed scale 1, no
+    shared expert; RMSNorm eps 1e-5, rope theta 1e6."""
+    return ModelConfig(
+        name="lfm2-24b-a2b", num_layers=40, d_model=2048, num_heads=32,
+        num_kv_heads=8, head_dim=64, d_ff=11776, vocab_size=65536,
+        mlp_type="swiglu", layer_types=_LFM2_LAYER_TYPES, num_dense_layers=2,
+        conv_kernel=3, qk_norm=True, rope_theta=1e6, rmsnorm_eps=1e-5,
+        moe=MoEConfig(num_experts=64, top_k=4, d_ff_expert=1536,
+                      router="sigmoid", expert_bias=True, norm_topk_prob=True,
+                      routed_scaling_factor=1.0),
+        tie_embeddings=True, subquadratic=False,
+    )
+
+
+def lfm2_24b_a2b_smoke() -> ModelConfig:
+    """Two dense conv layers and one (attention, conv, conv, conv) period."""
+    return dataclasses.replace(
+        lfm2_24b_a2b(), name="lfm2-24b-a2b-smoke", num_layers=6, d_model=64,
+        num_heads=4, num_kv_heads=2, head_dim=16, d_ff=128, vocab_size=256,
+        layer_types=_LFM2_LAYER_TYPES[:6],
+        moe=MoEConfig(num_experts=8, top_k=2, d_ff_expert=32, router="sigmoid",
+                      expert_bias=True, norm_topk_prob=True,
+                      routed_scaling_factor=1.0),
+    )
+
+
 ARCHS = {
     "grok-1-314b": grok_1_314b,
     "arctic-480b": arctic_480b,
@@ -251,6 +288,7 @@ ARCHS = {
     "hymba-1.5b": hymba_1_5b,
     "llava-next-mistral-7b": llava_next_mistral_7b,
     "mamba2-370m": mamba2_370m,
+    "lfm2-24b-a2b": lfm2_24b_a2b,
 }
 
 SMOKES = {
@@ -264,6 +302,7 @@ SMOKES = {
     "hymba-1.5b": hymba_1_5b_smoke,
     "llava-next-mistral-7b": llava_next_mistral_7b_smoke,
     "mamba2-370m": mamba2_370m_smoke,
+    "lfm2-24b-a2b": lfm2_24b_a2b_smoke,
 }
 
 

@@ -50,7 +50,7 @@ def smoke_shape(spec: ShapeSpec) -> ShapeSpec:
 
 
 def all_cells():
-    """The 40 assigned (arch x shape) cells, with applicability flags."""
+    """Every (arch x shape) cell, with applicability flags."""
     from repro.configs.archs import ARCHS
 
     cells = []

@@ -256,6 +256,7 @@ class SessionEpochStats:
     num_rows: int
     attributed: list  # [S] cumulative ledger attribution snapshot
     level_lanes: list  # [F] valid merged lanes per function level
+    expert_load: float  # the trunk's busiest expert over the mean; 0 where no expert ran
     answer_mask: Optional[np.ndarray] = None  # [S, C] when collect_masks
     true_f: Optional[list] = None  # [S] when the program carries truth_masks
 
@@ -518,9 +519,7 @@ class EpochProgram:
                 merged = plan_lib.quarantine_filter(merged, state.quarantined)
         return plans, merged, want_bits
 
-    def _gather_outputs(
-        self, state: SessionState, merged: plan_lib.Plan, bank_params
-    ) -> jax.Array:
+    def _bank_part(self, state: SessionState, merged: plan_lib.Plan, bank_params):
         """The bank boundary, fully inside the trace.
 
         With an attached bank, the merged plan runs through the bank's pure
@@ -531,14 +530,20 @@ class EpochProgram:
         capacity-padded ``state.bank_outputs`` buffer; invalid merged lanes
         route to row 0 (NOT clipped onto row capacity-1, a real row once the
         session fills) and stay inert: apply drops them, chargeable/want-bits
-        are valid-masked.
+        are valid-masked.  -> (outputs, expert_load): the bank's
+        ``execute_with_stats`` reading where it has one, else 0.
         """
         with tracing.scope(tracing.BANK):
+            load = jnp.zeros((), jnp.float32)
             if self.bank is not None:
-                probs = self.bank.execute(merged, bank_params)
-                return probs.astype(state.substrate.func_probs.dtype)
+                if hasattr(self.bank, "execute_with_stats"):
+                    probs, bank_stats = self.bank.execute_with_stats(merged, bank_params)
+                    load = bank_stats["expert_load"]
+                else:
+                    probs = self.bank.execute(merged, bank_params)
+                return probs.astype(state.substrate.func_probs.dtype), load
             obj = plan_lib.gather_object_idx(merged, state.capacity)
-            return state.bank_outputs[obj, merged.pred_idx, jnp.maximum(merged.func_idx, 0)]
+            return state.bank_outputs[obj, merged.pred_idx, jnp.maximum(merged.func_idx, 0)], load
 
     def _apply_part(self, state, plans, merged, want_bits, outputs):
         """The superstep past the bank boundary: charge, apply, attribute,
@@ -605,8 +610,9 @@ class EpochProgram:
         """One plan -> execute -> apply -> attribute epoch as a pure scan body."""
         self._trace_count += 1  # Python side effect: fires per TRACE, not per step
         plans, merged, want_bits = self._plan_part(state)
-        outputs = self._gather_outputs(state, merged, bank_params)
+        outputs, expert_load = self._bank_part(state, merged, bank_params)
         new_state, stats = self._apply_part(state, plans, merged, want_bits, outputs)
+        stats["expert_load"] = expert_load
         if not collect_masks:
             stats = {k: v for k, v in stats.items() if k != "answer_mask"}
         return new_state, stats
@@ -759,11 +765,13 @@ class EpochProgram:
         """Build ``SessionEpochStats`` from chunked host-side scan stats,
         trimming post-exhaustion no-op epochs to match the loop driver.
         The ``pique.history`` span carries the merged lanes per function
-        level, summed over every epoch of ``hosts``."""
+        level and the epochs' ``expert_load``, each summed over every epoch
+        of ``hosts``."""
         counts = {}
         if hosts:
             lanes = sum(np.sum(s["level_lanes"], axis=0) for _, s in hosts)
             counts = {f"lanes_{i}": int(n) for i, n in enumerate(lanes)}
+            counts["expert_load"] = float(sum(np.sum(s["expert_load"]) for _, s in hosts))
         with tracing.span(tracing.HISTORY, **counts):
             history: list[SessionEpochStats] = []
             e = epoch_base
@@ -784,6 +792,7 @@ class EpochProgram:
                             num_rows=int(stats["num_rows"][i]),
                             attributed=[float(x) for x in stats["attributed"][i]],
                             level_lanes=[int(x) for x in stats["level_lanes"][i]],
+                            expert_load=float(stats["expert_load"][i]),
                             answer_mask=(
                                 np.asarray(stats["answer_mask"][i])
                                 if collect_masks

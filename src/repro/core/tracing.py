@@ -11,7 +11,11 @@ Two kinds, both read from a ``jax.profiler`` trace and nowhere else:
   ``op_name`` (``jit(run_fn)/while/body/.../pique/score/...``) and nothing
   else.  A device op belongs to the FIRST ``pique/`` scope of its op name,
   so work traced inside ``refresh`` counts there, and ``trunk`` is a
-  sub-scope of ``bank``.
+  sub-scope of ``bank``; ``experts`` and ``conv`` are sub-scopes of
+  ``trunk``.  XLA's TPU lowering of ``jax.lax.ragged_dot`` (the experts'
+  grouped matmuls) replaces that instruction's op name with its own,
+  ``ragged-dot-<n>``: the benchmark puts those ops down to ``experts`` by
+  that name.
 
 Tests, the benchmark's trace reduction and the README cite the names below.
 """
@@ -34,16 +38,19 @@ DRAIN = "drain"  # PendingRing.drain_into; slots, rows
 RUN = "run"  # EpochProgram.run_scan; epochs, traces
 DISPATCH = "dispatch"  # EpochProgram.dispatch_scan
 WAIT = "wait"  # run_scan's device_get and block_until_ready
-HISTORY = "history"  # materialize_history; lanes_0 .. lanes_{F-1}
+HISTORY = "history"  # materialize_history; lanes_0 .. lanes_{F-1}, expert_load
 
 # device scopes, one per superstep phase, plus the model trunk inside the
-# bank and REFRESH, the whole refresh program
+# bank (with its expert and conv layers inside it) and REFRESH, the whole
+# refresh program
 SCORE = "score"  # _benefits: scoring, the cost gather, valid masking
 CANDIDATES = "candidates"  # candidate_mask and restrict_benefits
 TOPK = "topk"  # select_plans_batched
 MERGE = "merge"  # merge_plans_dedup_wants, quarantine_filter
-BANK = "bank"  # _gather_outputs
+BANK = "bank"  # _bank_part
 TRUNK = "trunk"  # the backbone branch of ModelCascadeBank.execute
+EXPERTS = "experts"  # models/moe.moe_apply: router, sort, grouped matmuls, combine
+CONV = "conv"  # models/short_conv.conv_apply: the gated short convolution
 APPLY = "apply"  # chargeable_mask, apply_outputs_to_substrate, attribute_epoch
 DERIVE = "derive"  # _derive
 SELECT = "select"  # _select_answers

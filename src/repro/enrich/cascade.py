@@ -378,6 +378,10 @@ class ModelCascadeBank:
     # ---- execution ----------------------------------------------------------
 
     def execute(self, plan: Plan, params=None) -> jax.Array:
+        """``execute_with_stats`` without its stats."""
+        return self.execute_with_stats(plan, params)[0]
+
+    def execute_with_stats(self, plan: Plan, params=None):
         """Fused traceable execute: every unique (object, pred, level) triple
         of the merged plan in one fixed-shape program.
 
@@ -396,6 +400,10 @@ class ModelCascadeBank:
         fixed-shape jnp array — inside ``jit`` / ``lax.scan``.  ``params``
         (default: this bank's own ``params``) supplies every array read, so
         a traced caller passes them in as arguments.
+
+        -> (probabilities [M], stats): ``stats["expert_load"]`` is the most
+        tokens routed to one expert over the mean, the largest over the
+        trunk's expert layers (0 where the trunk has none or did not run).
         """
         if params is None:
             params = self.params
@@ -418,6 +426,7 @@ class ModelCascadeBank:
         lane = jnp.arange(m)
 
         out = jnp.full((m,), 0.5, jnp.float32)
+        expert_load = jnp.zeros((), jnp.float32)
         for j, (level, arrays) in enumerate(zip(self._levels, params["levels"])):
             on = s_valid & (s_fn == j) & self.available[s_prd, j]
             if level["kind"] == "backbone":
@@ -438,31 +447,33 @@ class ModelCascadeBank:
                         jnp.arange(N_BACKBONE_TOKENS)[None],
                         (m, N_BACKBONE_TOKENS),
                     )
-                    h, _, _ = tf.stack_apply(
+                    h, _, aux = tf.stack_apply(
                         trunk["layers"], cfg, x, pos, cfg.num_layers,
                         causal=False,
                     )
                     pooled = jnp.mean(h.astype(jnp.float32), axis=1)
                     logits = jnp.einsum("mk,pko->pmo", pooled, heads["out"])
-                    return jax.nn.sigmoid(logits[s_prd, lane, 0])
+                    return jax.nn.sigmoid(logits[s_prd, lane, 0]), aux.expert_load
 
                 # Skip the trunk entirely on epochs where the planner put no
                 # lane at this level — the in-trace twin of execute_host's
                 # ``if not sel.any(): continue``.  The branch result is only
                 # read where ``on`` holds, so the skip value never escapes.
-                probs = jax.lax.cond(
+                probs, load = jax.lax.cond(
                     jnp.any(on),
                     _backbone_probs,
-                    lambda operands: jnp.full((m,), 0.5, jnp.float32),
+                    lambda operands: (jnp.full((m,), 0.5, jnp.float32),
+                                      jnp.zeros((), jnp.float32)),
                     (feats, s_prd),
                 )
+                expert_load = jnp.maximum(expert_load, load)
             else:
                 per_pred = jax.vmap(level["apply"], in_axes=(0, None))(
                     arrays, feats
                 )  # [P, M]
                 probs = per_pred[s_prd, lane]
             out = jnp.where(on, probs.astype(jnp.float32), out)
-        return out[inv]
+        return out[inv], {"expert_load": expert_load}
 
     def execute_host(self, plan: Plan) -> jax.Array:
         """Legacy host dispatch: group triples by (pred, level) on the host

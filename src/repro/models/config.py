@@ -1,15 +1,19 @@
 """Model configuration for the enrichment-model zoo (assigned architectures).
 
-One composable decoder/enc-dec transformer family covers all ten assigned
-architectures; every architectural lever is a config field.  Layer mixers are
-described by a per-layer pattern cycled across depth:
+One composable decoder/enc-dec transformer family covers every architecture
+of the zoo; every architectural lever is a config field.  Layer mixers are
+described by a per-layer pattern cycled across depth, or by an explicit
+per-layer list (``layer_types``):
 
     "global"  — full (causal) GQA attention
     "local"   — sliding-window GQA attention (window = sliding_window)
     "mamba"   — Mamba-2 SSD mixer (attention-free)
     "hymba"   — parallel attention ∥ Mamba-2 heads (Hymba)
+    "conv"    — gated short convolution (LFM2; kernel = conv_kernel)
 
-MLPs: "swiglu" | "squared_relu" | "gelu" | "none" (mamba2 has no MLP).
+MLPs: "swiglu" | "squared_relu" | "gelu" | "none" (mamba2 has no MLP).  Each
+layer's feed-forward is "mlp" (width d_ff), "moe" or "none":
+``layer_kinds`` gives every layer's (mixer, feed-forward) pair.
 """
 
 from __future__ import annotations
@@ -21,13 +25,26 @@ from typing import Optional, Sequence
 import jax.numpy as jnp
 
 
+ATTENTION_MIXERS = ("global", "local", "hymba")
+SSM_MIXERS = ("mamba", "hymba")
+
+
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
+    """Token-choice experts, dropless.  ``router`` "softmax": top-k of the
+    softmax; "sigmoid" (LFM2): top-k of sigmoid scores plus a per-expert
+    bias that only chooses (``expert_bias``).  The chosen scores are divided
+    by their sum where ``norm_topk_prob``, then scaled by
+    ``routed_scaling_factor``."""
+
     num_experts: int
     top_k: int = 2
     d_ff_expert: int = 0  # per-expert hidden size
     dense_residual: bool = False  # Arctic: dense FFN in parallel with MoE
-    capacity_factor: float = 1.25
+    router: str = "softmax"  # "softmax" | "sigmoid"
+    expert_bias: bool = False  # selection-only bias [E] (LFM2 use_expert_bias)
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
     router_z_loss: float = 1e-3
     load_balance_loss: float = 1e-2
 
@@ -70,6 +87,9 @@ class ModelConfig:
     vocab_size: int
     mlp_type: str = "swiglu"
     layer_pattern: tuple = ("global",)  # cycled over layers
+    layer_types: Optional[tuple] = None  # explicit mixer per layer (wins over the pattern)
+    num_dense_layers: int = 0  # leading layers with a d_ff MLP in an MoE model
+    conv_kernel: int = 3  # "conv" mixer taps (LFM2 conv_L_cache)
     sliding_window: Optional[int] = None
     qk_norm: bool = False
     attn_logit_softcap: Optional[float] = None
@@ -95,15 +115,19 @@ class ModelConfig:
         return jnp.dtype(self.dtype)
 
     def mixer_of_layer(self, i: int) -> str:
+        if self.layer_types is not None:
+            return self.layer_types[i]
         return self.layer_pattern[i % len(self.layer_pattern)]
 
-    @property
-    def uses_attention(self) -> bool:
-        return any(m in ("global", "local", "hymba") for m in self.layer_pattern)
+    def ffn_of_layer(self, i: int) -> str:
+        if self.moe is not None and i >= self.num_dense_layers:
+            return "moe"
+        return "none" if self.mlp_type == "none" or self.d_ff == 0 else "mlp"
 
-    @property
-    def uses_ssm(self) -> bool:
-        return any(m in ("mamba", "hymba") for m in self.layer_pattern)
+    def layer_kinds(self, num_layers: Optional[int] = None) -> tuple:
+        """(mixer, feed-forward) of each of the first ``num_layers`` layers."""
+        n = self.num_layers if num_layers is None else num_layers
+        return tuple((self.mixer_of_layer(i), self.ffn_of_layer(i)) for i in range(n))
 
     @property
     def q_per_kv(self) -> int:
@@ -139,6 +163,11 @@ class ModelConfig:
             active += dense
         return total, active
 
+    def _conv_params(self) -> int:
+        """in_proj d -> 3d (B, C, x), depthwise taps, out_proj; no biases."""
+        d = self.d_model
+        return 3 * d * d + self.conv_kernel * d + d * d
+
     def _ssm_params(self) -> int:
         if self.ssm is None:
             return 0
@@ -159,19 +188,20 @@ class ModelConfig:
         total = embed + unembed
         active = embed + unembed
         enc_layers = self.encoder.num_layers if self.encoder else 0
-        for i in range(self.num_layers):
-            mixer = self.mixer_of_layer(i)
+        for mixer, ffn in self.layer_kinds():
             layer_t = layer_a = 0
-            if mixer in ("global", "local", "hybrid", "hymba"):
+            if mixer in ATTENTION_MIXERS:
                 layer_t += self._attn_params()
-            if mixer in ("mamba", "hymba"):
+            if mixer in SSM_MIXERS:
                 layer_t += self._ssm_params()
+            if mixer == "conv":
+                layer_t += self._conv_params()
             layer_a = layer_t
-            if self.moe is not None:
+            if ffn == "moe":
                 mt, ma = self._moe_params()
                 layer_t += mt
                 layer_a += ma
-            else:
+            elif ffn == "mlp":
                 layer_t += self._mlp_params()
                 layer_a += self._mlp_params()
             total += layer_t

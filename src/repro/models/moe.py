@@ -1,24 +1,30 @@
-"""Mixture-of-Experts layer (grok-1: 8e top-2; arctic: 128e top-2 + dense
-residual), TPU-native gather dispatch.
+"""Mixture-of-Experts layer, dropless (grok-1: 8e top-2; arctic: 128e top-2 +
+dense residual; LFM2-24B-A2B: 64e top-4, sigmoid router with a selection
+bias).
 
-Token-choice top-k gating with expert-capacity truncation: each expert gathers
-its top-C tokens by gate weight (ties to GShard/Switch capacity semantics —
-over-capacity tokens are dropped for that expert).  Dispatch is two gathers +
-two batched GEMMs + one scatter-add: no [T, E, C] one-hot tensors, no host
-control flow, shape-stable under jit/SPMD.
+Every token of the call goes to each of its top-k experts; none is ever
+dropped.  Dispatch works over all the call's tokens at once, flattened
+across sequences: the (token, expert) pairs are sorted by expert, the
+gathered rows run through grouped matmuls (``jax.lax.ragged_dot``, one group
+per expert, group sizes counted from the routing), and the outputs return
+through the inverse permutation, weighted and summed over each token's k
+experts.  Work is proportional to tokens x k, whatever the routing.
 
-Expert dim shards over the "data" mesh axis when divisible (arctic 128e/16),
-else per-expert weights FSDP-shard (grok 8e) — see sharding.default_rules.
+The router follows the config: softmax top-k, or (LFM2) ``s = sigmoid(x W)``
+with experts chosen by top-k of ``s + expert_bias`` and weighted by ``s`` at
+the chosen experts.  Weights are divided by their sum (``norm_topk_prob``;
+plus 1e-6 under the sigmoid router, as HF ``lfm2_moe``) and scaled by
+``routed_scaling_factor``.
 """
 
 from __future__ import annotations
 
-import math
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 
+from repro.core import tracing
 from repro.models.activation_sharding import shard_act
 from repro.models.layers import _dense_init
 
@@ -26,6 +32,7 @@ from repro.models.layers import _dense_init
 class MoEAux(NamedTuple):
     load_balance_loss: jax.Array
     router_z_loss: jax.Array
+    expert_load: jax.Array  # most tokens routed to one expert, over the mean
 
 
 def moe_init(key, cfg):
@@ -46,89 +53,73 @@ def moe_init(key, cfg):
     if swiglu:
         params["wg"] = _dense_init(ks[3], (m.num_experts, d, f), in_axis=1)
         axes["wg"] = ("experts", "expert_embed", "mlp")
+    if m.expert_bias:
+        params["expert_bias"] = jnp.zeros((m.num_experts,), jnp.float32)
+        axes["expert_bias"] = (None,)
     return params, axes
 
 
-def expert_capacity(tokens: int, num_experts: int, top_k: int, factor: float) -> int:
-    c = int(math.ceil(factor * tokens * top_k / num_experts))
-    return max(8, -(-c // 8) * 8)  # round up to a multiple of 8
+def route(params, cfg, x: jax.Array):
+    """x: [T, d] -> (expert ids [T, k], weights [T, k] f32, router logits
+    [T, E] f32).  Logits are computed in float32."""
+    m = cfg.moe
+    logits = jnp.dot(
+        x.astype(jnp.float32), params["router"].astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+    )
+    if m.router == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        choose = scores + params["expert_bias"] if m.expert_bias else scores
+        _, idx = jax.lax.top_k(choose, m.top_k)
+        w = jnp.take_along_axis(scores, idx, axis=-1)
+        eps = 1e-6
+    elif m.router == "softmax":
+        w, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), m.top_k)
+        eps = 0.0
+    else:
+        raise ValueError(f"unknown router {m.router!r}")
+    if m.norm_topk_prob:
+        w = w / jnp.maximum(w.sum(-1, keepdims=True) + eps, 1e-9)
+    return idx, w * m.routed_scaling_factor, logits
 
 
-def _group_len(s: int, target: int = 4096) -> int:
-    """Largest divisor of s that is <= target (dispatch group length)."""
-    if s <= target:
-        return s
-    best = 1
-    for cand in range(1, target + 1):
-        if s % cand == 0:
-            best = cand
-    return best
-
-
+@tracing.scope(tracing.EXPERTS)
 def moe_apply(params, cfg, x: jax.Array) -> tuple[jax.Array, MoEAux]:
-    """x: [B, S, d] -> (y, aux losses).
-
-    Dispatch is per GROUP (GShard-style): tokens are grouped along (batch,
-    seq-chunk) so all top-k / gather / scatter traffic stays inside the data
-    shard that owns the tokens — no global sorts, no cross-shard gathers.
-    Each expert takes its top-C tokens per group (capacity truncation)."""
+    """x: [B, S, d] -> (y, aux)."""
     m = cfg.moe
     dt = x.dtype
     b, s, d = x.shape
-    gl = _group_len(s)
-    ng = b * (s // gl)
-    xg_in = shard_act(x.reshape(ng, gl, d), "batch", None, "act_embed")
+    t, k, e = b * s, m.top_k, m.num_experts
+    xf = shard_act(x.reshape(t, d), "batch", "act_embed")
+    idx, w, logits = route(params, cfg, xf)
 
-    logits = (xg_in @ params["router"].astype(dt)).astype(jnp.float32)  # [G,T,E]
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_vals, top_idx = jax.lax.top_k(probs, m.top_k)  # [G, T, k]
-    top_vals = top_vals / jnp.maximum(top_vals.sum(-1, keepdims=True), 1e-9)
+    # sort the t*k (token, expert) pairs by expert; stable, so each expert's
+    # rows keep token order
+    flat = idx.reshape(t * k)
+    order = jnp.argsort(flat, stable=True)
+    inv = jnp.zeros_like(order).at[order].set(jnp.arange(t * k, dtype=order.dtype))
+    sizes = jnp.bincount(flat, length=e).astype(jnp.int32)  # rows per expert
+    xs = xf[order // k]  # [t*k, d]
 
-    # gate matrix [G, T, E]: renormalized top-k weights, zero elsewhere
-    gates = jnp.zeros((ng, gl, m.num_experts), jnp.float32)
-    g_ar = jnp.arange(ng)[:, None, None]
-    t_ar = jnp.arange(gl)[None, :, None]
-    gates = gates.at[g_ar, t_ar, top_idx].set(top_vals)
+    def gmm(lhs, rhs):
+        return jax.lax.ragged_dot(lhs, rhs.astype(dt), sizes)
 
-    # --- capacity-truncated dispatch: top-C tokens per (group, expert) -----
-    cap = expert_capacity(gl, m.num_experts, m.top_k, m.capacity_factor)
-    cap = min(cap, gl)
-    sel_w, sel_idx = jax.lax.top_k(
-        jnp.swapaxes(gates, 1, 2), cap
-    )  # [G, E, C] weights + in-group token ids
-    live = (sel_w > 0.0).astype(jnp.float32)
-
-    xe = jnp.take_along_axis(
-        xg_in[:, None, :, :], sel_idx[..., None], axis=2
-    )  # [G, E, C, d]
-    xe = shard_act(xe, "batch", None, None, "act_embed")
     if cfg.mlp_type in ("swiglu", "geglu"):
-        gproj = jnp.einsum("gecd,edf->gecf", xe, params["wg"].astype(dt))
-        uproj = jnp.einsum("gecd,edf->gecf", xe, params["wu"].astype(dt))
         act = jax.nn.silu if cfg.mlp_type == "swiglu" else jax.nn.gelu
-        h = act(gproj) * uproj
+        h = act(gmm(xs, params["wg"])) * gmm(xs, params["wu"])
     else:
-        h = jnp.einsum("gecd,edf->gecf", xe, params["wu"].astype(dt))
+        h = gmm(xs, params["wu"])
         h = (
             jnp.square(jax.nn.relu(h))
             if cfg.mlp_type == "squared_relu"
             else jax.nn.gelu(h)
         )
-    h = shard_act(h, "batch", None, None, "act_ff")
-    out_e = jnp.einsum("gecf,efd->gecd", h, params["wd"].astype(dt))  # [G,E,C,d]
-    out_e = shard_act(out_e, "batch", None, None, "act_embed")
-    out_e = out_e * (sel_w * live)[..., None].astype(dt)
+    out = gmm(h, params["wd"])[inv].reshape(t, k, d)  # back in (token, k) order
+    y = jnp.einsum("tkd,tk->td", out.astype(jnp.float32), w).astype(dt)
 
-    y = jnp.zeros((ng, gl, d), dt).at[
-        jnp.arange(ng)[:, None, None], sel_idx
-    ].add(out_e)
-
-    # --- aux losses (Switch-style) ------------------------------------------
-    me = jnp.mean(probs, axis=(0, 1))  # mean router prob per expert
-    routed = jnp.zeros((ng, gl, m.num_experts), jnp.float32).at[
-        g_ar, t_ar, top_idx
-    ].set(1.0)
-    ce = jnp.mean(routed, axis=(0, 1))  # fraction of tokens per expert
-    lb = m.num_experts * jnp.sum(me * ce)
+    # aux: Switch-style losses and the load of the busiest expert
+    probs = jax.nn.softmax(logits, axis=-1)
+    lb = e * jnp.sum(jnp.mean(probs, axis=0) * (sizes / t))
     z = jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1)))
-    return y.reshape(b, s, d), MoEAux(lb, z)
+    load = jnp.max(sizes).astype(jnp.float32) * e / (t * k)
+    return y.reshape(b, s, d), MoEAux(lb, z, load)

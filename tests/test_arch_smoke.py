@@ -95,10 +95,10 @@ def test_smoke_prefill_decode(arch):
 def test_decode_matches_prefill_incremental():
     """Teacher-forced decode must reproduce prefill logits (cache correctness).
 
-    Run on a dense arch, an SSM arch, a hybrid and the local-attention arch so
-    every cache type is covered.
+    Run on a dense arch, an SSM arch, a hybrid, the local-attention arch and
+    the short-conv / expert arch so every cache type is covered.
     """
-    for arch in ("qwen3-1.7b", "mamba2-370m", "hymba-1.5b", "gemma2-9b"):
+    for arch in ("qwen3-1.7b", "mamba2-370m", "hymba-1.5b", "gemma2-9b", "lfm2-24b-a2b"):
         cfg = get_config(arch, smoke=True)
         cfg = dataclasses.replace(cfg, remat=False)
         model = Model(cfg)
@@ -137,6 +137,7 @@ def test_param_counts_match_public_sizes():
         "llava-next-mistral-7b": (7e9, 0.25),
         "hymba-1.5b": (1.5e9, 0.35),
         "seamless-m4t-large-v2": (2.3e9, 0.5),
+        "lfm2-24b-a2b": (24e9, 0.10),
     }
     for arch, (target, tol) in expected.items():
         cfg = get_config(arch)

@@ -59,8 +59,8 @@ def _probe_bank(num_preds=3, n=48, seed=0, ragged_pred=None):
     return ModelCascadeBank(cascades=suite, features=feats)
 
 
-def _backbone_bank(num_preds=2, n=24, seed=0):
-    cfg = get_config("qwen3-1.7b", smoke=True)
+def _backbone_bank(num_preds=2, n=24, seed=0, arch="qwen3-1.7b"):
+    cfg = get_config(arch, smoke=True)
     suite = build_cascade_suite(
         jax.random.PRNGKey(seed), num_preds, FEATURE_DIM, backbone_cfg=cfg
     )
@@ -154,8 +154,9 @@ def test_execute_parity_probe_bank(seed):
     assert np.all(fused[inv] == 0.5)
 
 
-def test_execute_parity_backbone_bank():
-    bank = _backbone_bank()
+@pytest.mark.parametrize("arch", ["qwen3-1.7b", "lfm2-24b-a2b"])
+def test_execute_parity_backbone_bank(arch):
+    bank = _backbone_bank(arch=arch)
     plan = _random_plan(bank, m=24, seed=1)
     fused = np.asarray(bank.execute(plan))
     host = np.asarray(bank.execute_host(plan))

@@ -22,11 +22,11 @@ from repro.launch.rules import rules_for_cell
 
 def test_all_cells_inventory():
     cells = all_cells()
-    assert len(cells) == 40
+    assert len(cells) == 44
     runnable = [c for c in cells if c["runnable"]]
     skipped = [c for c in cells if not c["runnable"]]
-    assert len(runnable) == 34
-    assert len(skipped) == 6
+    assert len(runnable) == 37
+    assert len(skipped) == 7
     assert all(c["shape"] == "long_500k" for c in skipped)
 
 

@@ -448,7 +448,7 @@ def test_plan_cost_lookup_matches_old_rule(
         @jax.jit
         def superstep(st):  # EpochProgram._superstep, keeping its plans
             plans, merged, want_bits = prog._plan_part(st)
-            outputs = prog._gather_outputs(st, merged, None)
+            outputs = prog._bank_part(st, merged, None)[0]
             st, stats = prog._apply_part(st, plans, merged, want_bits, outputs)
             return st, (plans, merged, want_bits, stats["requested_cost"],
                         stats["cost_spent"], st.ledger)

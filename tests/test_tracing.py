@@ -124,9 +124,56 @@ def test_session_host_spans_carry_their_counts(tmp_path):
     assert args["run"] == [{"epochs": 4, "traces": 1}]
     # one refresh per admit, retire and drain that moved rows
     assert names.count("refresh") == 2 + len([a for a in args["drain"] if a["rows"]])
-    lanes = args["history"][0]
+    history = args["history"][0]
+    lanes = {k: v for k, v in history.items() if k.startswith("lanes_")}
     assert sorted(lanes) == [f"lanes_{i}" for i in range(session.num_functions)]
     assert sum(lanes.values()) == sum(h.merged_valid for h in hist)
+    assert set(history) - set(lanes) == {"expert_load"} and history["expert_load"] == 0
+
+
+@pytest.fixture(scope="module")
+def lfm2():
+    """A session served through ``build_cascade_session_server`` whose model
+    level is the LFM2-MoE smoke trunk (conv, attention and expert layers)."""
+    session, state, preds, _ = build_cascade_session_server(
+        num_objects=32, num_preds=2, max_tenants=2, backbone_arch="lfm2-24b-a2b",
+        plan_size=8,
+    )
+    return session, state, preds
+
+
+def test_trunk_branch_holds_the_expert_and_conv_scopes(lfm2):
+    session, state, preds = lfm2
+    st, _ = session.admit(state, conjunction(preds[0]))
+    session.run(st, 2, chunk_size=2, stop_when_exhausted=False)
+    step = dict(session.program.compiled_hlo())["superstep"]
+    names = re.findall(r'op_name="([^"]*)"', step)
+    branch = "pique/bank/cond/branch_1_fun/pique/trunk/"
+    for sub in (tracing.EXPERTS, tracing.CONV):
+        inside = [n for n in names if branch in n and f"pique/{sub}/" in n]
+        assert inside, sub
+        # nested inside the trunk, inside the bank: never an op's first scope
+        # where the whole path is named
+        assert all(n.index("pique/bank/") < n.index(f"pique/{sub}/") for n in inside)
+    assert tracing.TRUNK not in _first_scopes(step)
+
+
+def test_expert_load_is_summed_on_the_history_span(lfm2, tmp_path):
+    session, state, preds = lfm2
+
+    def serve():
+        st, _ = session.admit(state, conjunction(preds[0]))
+        return session.run(st, 16, chunk_size=2, stop_when_exhausted=False)[1]
+
+    hist, spans = _trace(tmp_path, serve)
+    history = [a for n, a in spans if n == tracing.HISTORY]
+    assert len(history) == 1
+    trunk = [h for h in hist if h.level_lanes[2] > 0]
+    assert trunk, "the planner bought no model-level triple"
+    # the busiest of 8 experts holds at least its top-2 share, at most every token
+    assert all(1.0 <= h.expert_load <= 4.0 for h in trunk)
+    assert all(h.expert_load == 0.0 for h in hist if h.level_lanes[2] == 0)
+    assert history[0]["expert_load"] == pytest.approx(sum(h.expert_load for h in hist))
 
 
 def test_level_lanes_sum_to_merged_valid_and_count_the_executed_bits(cascade):
