@@ -44,12 +44,17 @@ def _arch(cfg: dict):
         layer_types=tuple(MIXERS[t] for t in cfg["layer_types"]), qk_norm=True,
         mlp_type="swiglu", num_experts=cfg["num_experts"], top_k=cfg["num_experts_per_tok"],
         d_ff_expert=cfg["moe_intermediate_size"], router="sigmoid",
-        expert_bias=cfg["use_expert_bias"], norm_topk_prob=cfg["norm_topk_prob"],
-        routed_scaling_factor=float(cfg["routed_scaling_factor"]),
     )
     got = {k: getattr(mc.moe if hasattr(mc.moe, k) else mc, k) for k in want}
-    if got != want or cfg["conv_bias"]:
+    if got != want:
         raise ValueError(f"program's {cfg['backbone_arch']} config {got} != published {want}")
+    # the program's sigmoid router always carries the selection bias,
+    # normalises the top-k weights and applies no routed scale
+    fixed = dict(use_expert_bias=True, norm_topk_prob=True, routed_scaling_factor=1,
+                 conv_bias=False)
+    if any(cfg[k] != v for k, v in fixed.items()):
+        raise ValueError(f"the program runs LFM2 only with {fixed}, the configuration "
+                         f"has {({k: cfg[k] for k in fixed})}")
     return dataclasses.replace(mc, num_layers=cfg["num_hidden_layers"])
 
 
@@ -235,7 +240,9 @@ def build(cfg: dict, traffic: dict, key_seed: int) -> dict:
     @functools.lru_cache(maxsize=None)
     def layer_fn(kind):
         """The program's layer ``kind`` on one object batch -> (output,
-        chosen experts or None), the choice read from ``moe.route``."""
+        chosen experts, margin) with the choice read from ``moe.route`` and
+        the margin the k-th choice score holds over the (k+1)-th (both None
+        for a dense layer)."""
         mixer, ffn = kind
 
         @jax.jit
@@ -243,31 +250,41 @@ def build(cfg: dict, traffic: dict, key_seed: int) -> dict:
             pos = jnp.broadcast_to(jnp.arange(x.shape[1])[None], x.shape[:2])
             mixed = {k: v for k, v in lp.items() if k not in ("mlp", "moe")}
             mid = tf.block_apply(mixed, mc, mixer, x, pos, causal=False)[0]
-            chosen = None
+            chosen = margin = None
             if ffn == "moe":
                 h2 = nn.rmsnorm(mid, lp["ln2"], mc.rmsnorm_eps)
-                chosen = moe.route(lp["moe"], mc, h2.reshape(-1, d))[0]
-            return tf.block_apply(lp, mc, mixer, x, pos, causal=False)[0], chosen
+                chosen, _, logits = moe.route(lp["moe"], mc, h2.reshape(-1, d))
+                top = jax.lax.top_k(jax.nn.sigmoid(logits) + lp["moe"]["expert_bias"],
+                                    mc.moe.top_k + 1)[0]
+                margin = top[:, -2] - top[:, -1]
+            return tf.block_apply(lp, mc, mixer, x, pos, causal=False)[0], chosen, margin
 
         return f
 
-    def routing_gap(o, q, ref_routes) -> float:
-        """Share of (token, expert layer) routings in which the program
-        chose other experts than the reference, on these objects."""
+    def routing_gap(o, q, ref_routes) -> dict:
+        """Where the program chose other experts than the reference, on
+        these objects: the share of (token, expert layer) routings over
+        all layers and per layer in depth order, and the median margin of
+        the program's k-th choice over its (k+1)-th, over all routings and
+        over the differing ones (near-ties flip under rounding)."""
         x = jnp.einsum("bf,bfd->bd", feats[jnp.asarray(o)], heads["proj"][jnp.asarray(q)])
         x = jnp.tile(x[:, None, :], (1, cfg["backbone_tokens"], 1)).astype(mc.activation_dtype)
-        differ, total, layer = 0, 0, 0
+        differs, margins, layer = [], [], 0
         for i in range(L):
             pos, g = where[i]
             kind = positions[pos][0]
-            x, chosen = layer_fn(kind)(jax.tree.map(lambda a: a[g], layers[pos]), x)
+            x, chosen, margin = layer_fn(kind)(jax.tree.map(lambda a: a[g], layers[pos]), x)
             if chosen is not None:
                 got = np.sort(np.asarray(chosen), axis=-1)
                 want = np.sort(ref_routes[layer], axis=-1)
-                differ += int(np.sum(np.any(got != want, axis=-1)))
-                total += got.shape[0]
+                differs.append(np.any(got != want, axis=-1))
+                margins.append(np.asarray(margin))
                 layer += 1
-        return differ / max(total, 1)
+        differ, margin = np.concatenate(differs), np.concatenate(margins)
+        return dict(share=float(differ.mean()),
+                    by_layer=[round(float(v.mean()), 4) for v in differs],
+                    margin_all=float(np.median(margin)),
+                    margin_differ=float(np.median(margin[differ])) if differ.any() else None)
 
     memo: dict = {}
 
@@ -286,9 +303,12 @@ def build(cfg: dict, traffic: dict, key_seed: int) -> dict:
         out = lfm2_moe.tag(host_feats[o], host_heads["proj"][q], host_heads["out"][q, :, 0],
                            trunk_layers(weights), arch, cfg["backbone_tokens"], routes)
         if routes is not None:
-            print(f"[bench] routing_gap {routing_gap(o, q, routes)} over {len(o)} model "
-                  f"triples x {cfg['backbone_tokens']} positions x {len(routes)} expert "
-                  f"layers", file=sys.stderr, flush=True)
+            gap = routing_gap(o, q, routes)
+            print(f"[bench] routing_gap {gap['share']} over {len(o)} model triples x "
+                  f"{cfg['backbone_tokens']} positions x {len(routes)} expert layers; by "
+                  f"layer {gap['by_layer']}; median choice margin {gap['margin_all']} over "
+                  f"all routings, {gap['margin_differ']} over differing ones",
+                  file=sys.stderr, flush=True)
         memo[weights] = (key, out)
         return out
 

@@ -259,8 +259,7 @@ def lfm2_24b_a2b() -> ModelConfig:
         mlp_type="swiglu", layer_types=_LFM2_LAYER_TYPES, num_dense_layers=2,
         conv_kernel=3, qk_norm=True, rope_theta=1e6, rmsnorm_eps=1e-5,
         moe=MoEConfig(num_experts=64, top_k=4, d_ff_expert=1536,
-                      router="sigmoid", expert_bias=True, norm_topk_prob=True,
-                      routed_scaling_factor=1.0),
+                      router="sigmoid"),
         tie_embeddings=True, subquadratic=False,
     )
 
@@ -271,9 +270,7 @@ def lfm2_24b_a2b_smoke() -> ModelConfig:
         lfm2_24b_a2b(), name="lfm2-24b-a2b-smoke", num_layers=6, d_model=64,
         num_heads=4, num_kv_heads=2, head_dim=16, d_ff=128, vocab_size=256,
         layer_types=_LFM2_LAYER_TYPES[:6],
-        moe=MoEConfig(num_experts=8, top_k=2, d_ff_expert=32, router="sigmoid",
-                      expert_bias=True, norm_topk_prob=True,
-                      routed_scaling_factor=1.0),
+        moe=MoEConfig(num_experts=8, top_k=2, d_ff_expert=32, router="sigmoid"),
     )
 
 

@@ -22,8 +22,9 @@ The bank boundary inside the superstep takes one of two traceable forms:
 banks publishing a precomputed ``.outputs`` tensor (the simulated bank) are
 gathered from the session-carried capacity-padded buffer, and banks passed
 to the program as ``bank=`` (the model-cascade bank) have their pure-JAX
-``execute`` traced straight into the scan body — real model forwards with
-zero host round-trips per epoch.  The old per-epoch loop driver
+``execute_with_stats(plan, params) -> (probabilities, stats)`` traced
+straight into the scan body — real model forwards with zero host
+round-trips per epoch.  The old per-epoch loop driver
 (``run_loop``: jitted plan half, host ``bank.execute``, jitted apply half)
 is GONE; after the cascade bank became traceable nothing needed it.
 
@@ -292,18 +293,18 @@ class EpochProgram:
         costs: jax.Array,
         config: EngineConfig,
         truth_masks: Optional[jax.Array] = None,  # [S, C] bool (metrics only)
-        bank=None,  # traceable bank whose execute runs INSIDE the superstep
+        bank=None,  # traceable bank whose execute_with_stats runs INSIDE the superstep
     ):
         self.table = table
         self.combine_params = combine_params
         self.costs = jnp.asarray(costs, jnp.float32)
         self.config = config
-        # When a bank is attached, the superstep calls ``bank.execute(merged,
-        # params)`` in-trace with the bank's ``params`` pytree (features,
-        # model weights) passed to the compiled scan as an ARGUMENT, never
-        # baked in as constants; when absent, outputs gather from the
-        # state-carried ``bank_outputs`` buffer (banks publishing a
-        # precomputed ``.outputs`` tensor).
+        # When a bank is attached, the superstep calls
+        # ``bank.execute_with_stats(merged, params)`` in-trace with the
+        # bank's ``params`` pytree (features, model weights) passed to the
+        # compiled scan as an ARGUMENT, never baked in as constants; when
+        # absent, outputs gather from the state-carried ``bank_outputs``
+        # buffer (banks publishing a precomputed ``.outputs`` tensor).
         self.bank = bank
         if bank is not None and not scan_capable(bank):
             raise ValueError(
@@ -523,27 +524,24 @@ class EpochProgram:
         """The bank boundary, fully inside the trace.
 
         With an attached bank, the merged plan runs through the bank's pure
-        ``execute`` (real model forwards for the cascade bank); its f32
-        probabilities are quantized to the substrate storage dtype HERE —
+        ``execute_with_stats`` (real model forwards for the cascade bank);
+        its f32 probabilities are quantized to the substrate storage dtype HERE —
         the same boundary ``ingest`` quantizes at — so ``apply`` only ever
         sees conforming writes.  Otherwise outputs gather from the
         capacity-padded ``state.bank_outputs`` buffer; invalid merged lanes
         route to row 0 (NOT clipped onto row capacity-1, a real row once the
         session fills) and stay inert: apply drops them, chargeable/want-bits
-        are valid-masked.  -> (outputs, expert_load): the bank's
-        ``execute_with_stats`` reading where it has one, else 0.
+        are valid-masked.  -> (outputs, expert_load): the attached bank's
+        ``expert_load`` stat, 0 for the gather.
         """
         with tracing.scope(tracing.BANK):
-            load = jnp.zeros((), jnp.float32)
             if self.bank is not None:
-                if hasattr(self.bank, "execute_with_stats"):
-                    probs, bank_stats = self.bank.execute_with_stats(merged, bank_params)
-                    load = bank_stats["expert_load"]
-                else:
-                    probs = self.bank.execute(merged, bank_params)
-                return probs.astype(state.substrate.func_probs.dtype), load
+                probs, bank_stats = self.bank.execute_with_stats(merged, bank_params)
+                return (probs.astype(state.substrate.func_probs.dtype),
+                        bank_stats["expert_load"])
             obj = plan_lib.gather_object_idx(merged, state.capacity)
-            return state.bank_outputs[obj, merged.pred_idx, jnp.maximum(merged.func_idx, 0)], load
+            outputs = state.bank_outputs[obj, merged.pred_idx, jnp.maximum(merged.func_idx, 0)]
+            return outputs, jnp.zeros((), jnp.float32)
 
     def _apply_part(self, state, plans, merged, want_bits, outputs):
         """The superstep past the bank boundary: charge, apply, attribute,

@@ -378,7 +378,9 @@ class ModelCascadeBank:
     # ---- execution ----------------------------------------------------------
 
     def execute(self, plan: Plan, params=None) -> jax.Array:
-        """``execute_with_stats`` without its stats."""
+        """Probabilities [M] alone, as every bank's ``execute`` returns them
+        to the facades' per-epoch paths; the superstep calls
+        ``execute_with_stats``."""
         return self.execute_with_stats(plan, params)[0]
 
     def execute_with_stats(self, plan: Plan, params=None):

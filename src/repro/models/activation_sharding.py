@@ -39,3 +39,16 @@ def shard_act(x: jax.Array, *axes: Optional[str]) -> jax.Array:
     if len(axes) != x.ndim:
         raise ValueError(f"axes {axes} rank != array rank {x.ndim}")
     return jax.lax.with_sharding_constraint(x, rules.sharding(mesh, axes))
+
+
+def mesh_axes_of(axis: str):
+    """(mesh, mesh axes) the logical ``axis`` maps to in the active context;
+    None without a context or where the axis is not sharded."""
+    ctx = getattr(_CTX, "val", None)
+    if ctx is None:
+        return None
+    mesh, rules = ctx
+    entry = rules.sharding(mesh, (axis,)).spec[0]
+    if entry is None:
+        return None
+    return mesh, tuple(entry) if isinstance(entry, (tuple, list)) else (entry,)

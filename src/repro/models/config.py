@@ -33,18 +33,13 @@ SSM_MIXERS = ("mamba", "hymba")
 class MoEConfig:
     """Token-choice experts, dropless.  ``router`` "softmax": top-k of the
     softmax; "sigmoid" (LFM2): top-k of sigmoid scores plus a per-expert
-    bias that only chooses (``expert_bias``).  The chosen scores are divided
-    by their sum where ``norm_topk_prob``, then scaled by
-    ``routed_scaling_factor``."""
+    bias that only chooses.  The chosen scores are divided by their sum."""
 
     num_experts: int
     top_k: int = 2
     d_ff_expert: int = 0  # per-expert hidden size
     dense_residual: bool = False  # Arctic: dense FFN in parallel with MoE
-    router: str = "softmax"  # "softmax" | "sigmoid"
-    expert_bias: bool = False  # selection-only bias [E] (LFM2 use_expert_bias)
-    norm_topk_prob: bool = True
-    routed_scaling_factor: float = 1.0
+    router: str = "softmax"  # "softmax" | "sigmoid" (with a selection bias [E])
     router_z_loss: float = 1e-3
     load_balance_loss: float = 1e-2
 

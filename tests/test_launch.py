@@ -83,9 +83,13 @@ def test_spec_never_reuses_mesh_axis():
 @pytest.mark.slow
 def test_small_mesh_lower_compile(shape_name):
     """Every step kind lowers+compiles on an 8-device mesh in a subprocess
-    (keeps this process single-device)."""
+    (keeps this process single-device).  The MoE archs cover experts
+    replicated (grok: 8 experts) and expert-parallel (arctic, LFM2: experts
+    over "data"); in their train and prefill steps each data shard
+    dispatches its own tokens, so no collective carries the call's
+    (token, expert) pairs."""
     code = textwrap.dedent(f"""
-        import os
+        import os, re
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, dataclasses
         from repro.configs.archs import get_config
@@ -98,11 +102,17 @@ def test_small_mesh_lower_compile(shape_name):
             seq_len=128 if "{shape_name}" != "train_4k" else 64,
             global_batch=8,
         )
-        for arch in ("qwen3-1.7b", "grok-1-314b", "mamba2-370m",
-                     "seamless-m4t-large-v2", "hymba-1.5b"):
+        for arch in ("qwen3-1.7b", "grok-1-314b", "arctic-480b", "lfm2-24b-a2b",
+                     "mamba2-370m", "seamless-m4t-large-v2", "hymba-1.5b"):
             cfg = get_config(arch, smoke=True)
             built = build_step(cfg, spec, mesh)
-            built.fn.lower(*built.args).compile()
+            text = built.fn.lower(*built.args).compile().as_text()
+            if cfg.moe is not None and spec.kind != "decode":
+                pairs = spec.global_batch * spec.seq_len * cfg.moe.top_k
+                moved = re.findall(
+                    rf"= ([^ ]*[[,]{{pairs}}[],][^ ]*) "
+                    "(?:all-gather|all-reduce|all-to-all|collective-permute)", text)
+                assert not moved, (arch, moved)
             print(arch, "OK")
         print("ALL_OK")
     """)
