@@ -34,7 +34,7 @@ SYNC = "sync"  # the blocking read of state.active inside admit / retire
 REFRESH = "refresh"  # EpochProgram.refresh; also the scope of its program
 STAGE = "stage"  # IngestStream._stage; rows, waited
 PUSH = "push"  # PendingRing.push; blocked
-DRAIN = "drain"  # PendingRing.drain_into; slots, rows
+DRAIN = "drain"  # PendingRing.drain_into; slots, rows, refreshed
 RUN = "run"  # EpochProgram.run_scan; epochs, traces
 DISPATCH = "dispatch"  # EpochProgram.dispatch_scan
 WAIT = "wait"  # run_scan's device_get and block_until_ready

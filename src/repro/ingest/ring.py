@@ -12,6 +12,8 @@ buffer at the session's substrate dtype:
   refresh-free ingests and refreshes derived state once — bitwise identical
   to ingesting each batch directly (refresh is idempotent w.r.t. the
   substrate), minus the per-batch full-width refreshes and device reads.
+  ``refresh=False`` lands the slots and leaves that refresh owed; the next
+  drain with ``refresh=True`` pays it, even on an empty ring.
 
 Backpressure — enrichment falling behind arrivals — is a full ring at
 ``push`` time, resolved by policy:
@@ -26,8 +28,8 @@ Backpressure — enrichment falling behind arrivals — is a full ring at
   preserved end-to-end.  Lossless; overflow pays host memory + a second
   transfer instead of a stall.
 
-Every counter (pushes, drains, sheds, spills, blocks) is host-side
-bookkeeping — reading them never touches the device.
+Every counter (pushes, drains, sheds, spills, blocks, refreshes run and
+deferred) is host-side bookkeeping — reading them never touches the device.
 """
 
 from __future__ import annotations
@@ -104,7 +106,11 @@ class PendingRing:
             "spilled_batches": 0,
             "spilled_rows": 0,
             "blocked": 0,
+            "refreshes": 0,
+            "refreshes_deferred": 0,
         }
+        # rows landed by a refresh=False drain whose refresh is still owed
+        self._refresh_owed = False
 
     # ---- occupancy (host shadows, never a device read) ----------------------
 
@@ -213,7 +219,7 @@ class PendingRing:
 
     # ---- consumer side -------------------------------------------------------
 
-    def drain_into(self, session, state, num_rows: int):
+    def drain_into(self, session, state, num_rows: int, *, refresh: bool = True):
         """Apply every pending slot to ``state`` in arrival order.
 
         -> ``(state, num_rows, drained_rows)``.  Each slot lands as a
@@ -225,6 +231,18 @@ class PendingRing:
         re-enter freed slots FIFO and drain in the same pass, so a drain
         leaves the ring truly empty unless the spill queue outruns the ring
         again.
+
+        ``refresh=False`` lands the slots the same way and skips that
+        refresh, leaving it owed: the returned state holds rows whose
+        derived state is stale, and must not run a superstep, be sampled or
+        be checkpointed until a later drain with ``refresh=True`` settles
+        it.  That drain refreshes once if it landed rows or a refresh is
+        owed, so deferred drains followed by one settling drain give
+        bitwise the state of refreshing at every drain: the refresh is a
+        pure function of substrate, slot masks and row count, and the
+        refresh-free ingests never read derived state.  The ``pique.drain``
+        span's ``refreshed`` (0 or 1) and the ``refreshes`` /
+        ``refreshes_deferred`` counters record which drains paid.
 
         All-or-nothing: capacity is checked against the TOTAL pending rows
         (ring + spill queue) before any slot is applied, so a
@@ -248,8 +266,10 @@ class PendingRing:
                 requested=total,
             )
         drained = 0
+        refreshed = refresh and (total > 0 or self._refresh_owed)
         with tracing.span(
-            tracing.DRAIN, slots=self._count + len(self._spilled), rows=total
+            tracing.DRAIN, slots=self._count + len(self._spilled), rows=total,
+            refreshed=int(refreshed),
         ):
             while self._count or self._spilled:
                 while self._count:
@@ -271,6 +291,11 @@ class PendingRing:
                 # pass
                 while self._spilled and self._count < self.num_slots:
                     self._enqueue(jnp.asarray(self._spilled.popleft()))
-            if drained:
+            if refreshed:
                 state = session.program.refresh(state)
+                self._refresh_owed = False
+                self.counters["refreshes"] += 1
+            elif drained:
+                self._refresh_owed = True
+                self.counters["refreshes_deferred"] += 1
         return state, num_rows, drained

@@ -21,6 +21,11 @@ quantizes batch N+1 while the device absorbs batch N.  Throttling
 (``rate_rows_per_s``) and blocked-ring handling (``on_pressure`` drains,
 then the push retries) both live here so the serving loop stays a dumb
 event loop.
+
+A pressure drain may land rows without refreshing derived state
+(``PendingRing.drain_into(..., refresh=False)``): the session state after
+``feed`` is then not settled, and the caller settles it with a refreshing
+drain before it runs a superstep, samples or checkpoints it.
 """
 
 from __future__ import annotations
@@ -52,7 +57,10 @@ class IngestStream:
     (the callback drains the ring into the session — e.g.
     ``pipeline.drain_ring``) and retries the SAME device batch, so nothing
     is re-staged or re-transferred.  Without a callback the signal
-    propagates to the caller.
+    propagates to the caller.  The callback only has to free the ring: it
+    may drain with ``refresh=False`` and leave the refresh owed, as the
+    lockstep ``launch.serve.StreamingIngest`` does, as long as its caller
+    settles the state with a refreshing drain before the next superstep.
     """
 
     def __init__(

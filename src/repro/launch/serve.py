@@ -484,6 +484,12 @@ class StreamingIngest:
     (one device sync at attach, none per event), overlap drains through
     ``SessionPipeline.drain_ring`` against the in-flight carry — so a full
     ring under the ``block`` policy resolves itself instead of deadlocking.
+
+    Lockstep backpressure drains land rows without a refresh and leave it
+    owed; the next explicit ``drain`` pays it once.  So a ``state`` taken
+    after ``feed`` may hold rows whose derived state is stale: settle with
+    ``drain`` before running a superstep, sampling or checkpointing it
+    (``admit`` and ``retire`` refresh in full on their own).
     """
 
     def __init__(
@@ -502,7 +508,7 @@ class StreamingIngest:
         )
         self.stream = IngestStream(
             self.ring, batch_rows=batch_rows,
-            rate_rows_per_s=rate_rows_per_s, on_pressure=self.drain,
+            rate_rows_per_s=rate_rows_per_s, on_pressure=self._on_pressure,
         )
         self._session = session
         self._pipe = None
@@ -524,19 +530,33 @@ class StreamingIngest:
 
     @property
     def state(self):
-        """Lockstep only: the state after the last feed/drain."""
+        """Lockstep only: the state after the last feed/drain.  After a
+        ``feed`` it may hold rows landed by a backpressure drain whose
+        refresh is still owed; only the state after ``drain`` is settled."""
         return self._state
 
     def feed(self, rows) -> int:
         return self.stream.feed(rows)
 
+    def _on_pressure(self) -> None:
+        """A full ring: land its rows and, in lockstep, leave the refresh
+        owed — the settling ``drain`` recomputes derived state once for
+        every row landed since."""
+        self._drain(refresh=False)
+
     def drain(self) -> None:
-        if self._pipe is not None:
+        """Settle: land every pending row and refresh once if rows landed
+        or a backpressure drain left the refresh owed.  Call it before every
+        ``run`` and at the end of the trace."""
+        self._drain(refresh=True)
+
+    def _drain(self, *, refresh: bool) -> None:
+        if self._pipe is not None:  # refreshes at every drain
             if self._pipe.drain_ring(self.ring):
                 self.drains += 1
             return
         self._state, self._num_rows, drained = self.ring.drain_into(
-            self._session, self._state, self._num_rows
+            self._session, self._state, self._num_rows, refresh=refresh
         )
         if drained:
             self.drains += 1

@@ -32,6 +32,7 @@ from repro.core.state import (
 )
 from repro.data.synthetic import make_corpus
 from repro.ingest import IngestStream, PendingRing
+from repro.launch.serve import StreamingIngest, parse_trace, serve_session_trace
 
 P_GLOBAL, F, N = 4, 4, 96
 
@@ -64,6 +65,23 @@ def _session(capacity=N, max_tenants=2, dtype="float32", seed=0,
 def _rows(m, seed=1, dtype=jnp.float32):
     rng = np.random.default_rng(seed)
     return jnp.asarray(rng.uniform(0.05, 0.95, (m, P_GLOBAL, F)), dtype)
+
+
+def _assert_states_bitwise(a, b):
+    la, ta = jax.tree_util.tree_flatten(a)
+    lb, tb = jax.tree_util.tree_flatten(b)
+    assert ta == tb
+    for x, y in zip(jax.device_get(la), jax.device_get(lb)):
+        x, y = np.asarray(x), np.asarray(y)
+        assert (x.dtype, x.shape) == (y.dtype, y.shape)
+        assert x.tobytes() == y.tobytes()
+
+
+def _admitted(dtype):
+    sess, corpus, preds = _session(capacity=N, dtype=dtype)
+    st = sess.init_state(corpus.func_probs[:32])
+    st, _ = sess.admit(st, conjunction(preds[0], preds[1]))
+    return sess, st
 
 
 # ------------------------------------------------------------- ring basics --
@@ -207,15 +225,9 @@ def test_ring_fed_bitwise_matches_direct(dtype, policy):
     """Ring-fed ingestion (refresh-free burst + one refresh) is bitwise
     identical to direct per-batch ingest, for every policy x dtype — with
     the shed comparison feeding only the batches that survived."""
-    def build():
-        sess, corpus, preds = _session(capacity=N, dtype=dtype)
-        st = sess.init_state(corpus.func_probs[:32])
-        st, _ = sess.admit(st, conjunction(preds[0], preds[1]))
-        return sess, st
-
     batches = [_rows(8, seed=s, dtype=jnp.dtype(dtype)) for s in range(4)]
 
-    sess_r, st_r = build()
+    sess_r, st_r = _admitted(dtype)
     ring = PendingRing(sess_r, slot_rows=8, num_slots=2, policy=policy)
     num_rows, landed = 32, []
     for b in batches:
@@ -229,7 +241,7 @@ def test_ring_fed_bitwise_matches_direct(dtype, policy):
     st_r, num_rows, _ = ring.drain_into(sess_r, st_r, num_rows)
     st_r, hist_r = sess_r.run(st_r, 3, stop_when_exhausted=False)
 
-    sess_d, st_d = build()
+    sess_d, st_d = _admitted(dtype)
     for b in landed:
         st_d = sess_d.ingest(st_d, b)
     st_d, hist_d = sess_d.run(st_d, 3, stop_when_exhausted=False)
@@ -312,6 +324,88 @@ def test_stream_backpressure_callback_drains_and_retries():
     np.testing.assert_array_equal(
         np.asarray(holder["state"].bank_outputs[16:56]), wave
     )
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_lockstep_pressure_drains_defer_refresh_bitwise(dtype):
+    """Lockstep backpressure drains land rows without a refresh; the
+    settling ``drain`` refreshes once, and the settled state is bitwise the
+    state of a path that refreshes at every drain."""
+    wave = np.asarray(_rows(56, seed=5, dtype=jnp.dtype(dtype)))  # 7 batches
+
+    sess_l, st_l = _admitted(dtype)
+    ing = StreamingIngest(sess_l, batch_rows=8, num_slots=2)
+    ing.attach_lockstep(st_l)
+    ing.begin(st_l)
+    assert ing.feed(wave) == 56  # pushes 3, 5 and 7 find the ring full
+    ing.drain()
+    c = ing.counters()
+    assert c["refreshes"] == 1 and c["refreshes_deferred"] >= 3
+    assert c["blocked"] == c["refreshes_deferred"] and ing.drains == 4
+
+    sess_p, st_p = _admitted(dtype)
+    ring = PendingRing(sess_p, slot_rows=8, num_slots=2)
+    held = {"state": st_p, "rows": 32}
+
+    def refresh_every_drain():
+        held["state"], held["rows"], _ = ring.drain_into(
+            sess_p, held["state"], held["rows"], refresh=True
+        )
+
+    IngestStream(ring, batch_rows=8, on_pressure=refresh_every_drain).feed(wave)
+    refresh_every_drain()
+    assert ring.counters["refreshes"] == 4
+    assert ring.counters["refreshes_deferred"] == 0
+    _assert_states_bitwise(ing.state, held["state"])
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_settling_drain_pays_owed_refresh_on_empty_ring(dtype):
+    """A debt left by pressure drains is paid by ``drain`` even when no row
+    is left to land, and paid only once."""
+    sess, st = _admitted(dtype)
+    ing = StreamingIngest(sess, batch_rows=8, num_slots=2)
+    ing.attach_lockstep(st)
+    ing.begin(st)
+    ing.feed(np.asarray(_rows(24, seed=6, dtype=jnp.dtype(dtype))))
+    ing.stream.on_pressure()  # the pressure path empties the ring
+    assert ing.ring.occupied == 0
+    c = ing.counters()
+    assert (c["refreshes"], c["refreshes_deferred"]) == (0, 2)
+    unsettled = ing.state
+    ing.drain()
+    assert ing.counters()["refreshes"] == 1
+    _assert_states_bitwise(ing.state, sess.refresh(unsettled))
+    settled = ing.state
+    ing.drain()  # nothing landed, nothing owed: no refresh
+    assert ing.counters()["refreshes"] == 1
+    assert ing.state is settled
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_serve_lockstep_streaming_matches_direct_ingest(dtype):
+    """Serve's lockstep trace with an ingest over two ring fills reads the
+    same answers and spend as the trace that ingests each batch directly."""
+    def serve(spec, streaming):
+        sess, corpus, preds = _session(capacity=N, dtype=dtype)
+        ing = None
+        if streaming:
+            ing = StreamingIngest(sess, batch_rows=8, num_slots=2)
+        return serve_session_trace(
+            sess, sess.init_state(corpus.func_probs[:32]), parse_trace(spec),
+            pool=corpus.func_probs[32:], preds=preds, seed=3, chunk_size=2,
+            streaming=ing,
+        )
+
+    tail = "admit:2;run:4;retire:0;run:2"
+    rep_s = serve(f"ingest:40;{tail}", True)
+    rep_d = serve(";".join(["ingest:8"] * 5) + f";{tail}", False)
+    assert rep_s.ingest_counters["refreshes_deferred"] == 2
+    assert rep_s.ingest_counters["refreshes"] == 1
+    assert rep_s.num_rows == rep_d.num_rows == 72
+    assert rep_s.answer_digest == rep_d.answer_digest
+    assert rep_s.cost_hex == rep_d.cost_hex
+    assert rep_s.bills_hex == rep_d.bills_hex
 
 
 def test_stream_without_callback_propagates_backpressure():

@@ -122,8 +122,10 @@ def test_session_host_spans_carry_their_counts(tmp_path):
     assert [a["rows"] for a in args["stage"]] == [16, 16, 16]
     assert sum(a["blocked"] for a in args["push"]) == 1  # the third push found the ring full
     assert args["run"] == [{"epochs": 4, "traces": 1}]
-    # one refresh per admit, retire and drain that moved rows
-    assert names.count("refresh") == 2 + len([a for a in args["drain"] if a["rows"]])
+    # the pressure drain leaves its refresh to the settling drain: one
+    # refresh per admit, retire and settling drain
+    assert [a["refreshed"] for a in args["drain"]] == [0, 1]
+    assert names.count("refresh") == 2 + sum(a["refreshed"] for a in args["drain"])
     history = args["history"][0]
     lanes = {k: v for k, v in history.items() if k.startswith("lanes_")}
     assert sorted(lanes) == [f"lanes_{i}" for i in range(session.num_functions)]
